@@ -269,12 +269,16 @@ def _check_kernels(cur: dict, base: "dict | None") -> list[str]:
             f"{budget:.4f} ({ps['xla_ms_per_step']:.4f} x "
             f"{1 + KERNEL_PAPER_TOLERANCE:.2f})")
 
+    # the paper shape is one tile and stays bitwise; at the large shape the
+    # tile-wise and whole-buffer dots may round differently: a few f32 ulps
+    # of the |z| < 4 outputs (the bound tests/test_kernels.py holds)
     for label, sb in cur["step_buf"].items():
-        if sb["interpret_max_abs_diff"] != 0.0:
+        bound = 1e-6 if label == "large" else 0.0
+        if sb["interpret_max_abs_diff"] > bound:
             errors.append(
-                f"interpret-mode kernel is not bitwise equal to the jitted "
-                f"oracle at the {label} shape {sb['shape']}: max abs diff "
-                f"{sb['interpret_max_abs_diff']:.2e}")
+                f"interpret-mode kernel departs from the jitted oracle at "
+                f"the {label} shape {sb['shape']}: max abs diff "
+                f"{sb['interpret_max_abs_diff']:.2e} (> {bound:g})")
 
     if base is None:
         errors.append("baseline has no kernels section — refresh "

@@ -28,6 +28,8 @@ def main() -> None:
                          "identical schedules across cells")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from . import (baselines_compare, beyond_noniid, datasets_table,
                    fig1_convergence, fig2_comm, fig3_consensus, fig4_lambda,
                    fig5_connectivity, kernel_bench, runner_bench)

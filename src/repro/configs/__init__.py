@@ -4,7 +4,8 @@ Every assigned architecture has one module in this package defining
 ``CONFIG: ModelConfig`` with the exact assigned hyper-parameters (source
 cited in the module docstring).  ``get_config(name)`` resolves ids with
 dashes; ``smoke_variant`` produces the reduced CI model (<=2 layers,
-d_model<=512, <=4 experts) used by per-arch smoke tests.
+d_model<=512, <=4 experts) used by per-arch smoke tests, and
+``depth_variant`` keeps every published width and cuts only the depth.
 """
 
 from __future__ import annotations
@@ -15,7 +16,8 @@ import importlib
 from repro.models.api import ModelConfig
 
 __all__ = ["ARCHITECTURES", "INPUT_SHAPES", "InputShape", "get_config",
-           "smoke_variant", "list_archs", "shape_applicable"]
+           "smoke_variant", "depth_variant", "list_archs",
+           "shape_applicable"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,3 +98,13 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         max_position=4096,
         scan_chunk=16,
     )
+
+
+def depth_variant(cfg: ModelConfig, num_layers: int) -> ModelConfig:
+    """The published config with only its depth cut to ``num_layers``."""
+    if not 1 <= num_layers <= cfg.num_layers:
+        raise ValueError(f"{cfg.name} has {cfg.num_layers} layers; cannot "
+                         f"keep {num_layers}")
+    if num_layers == cfg.num_layers:
+        return cfg
+    return cfg.scaled(name=f"{cfg.name}-{num_layers}L", num_layers=num_layers)

@@ -158,10 +158,10 @@ def compressed_mix_permute(phi: gossip.PermutePhi, tree,
             mixed.append(acc)
         return tuple(mixed) + tuple(sent)
 
-    shard = gossip._shard_map(
-        _local, mesh,
-        (P(None, axis),) + tuple(P(axis) for _ in leaves),
-        tuple(P(axis) for _ in range(2 * k)))
+    shard = jax.shard_map(
+        _local, mesh=mesh,
+        in_specs=(P(None, axis),) + tuple(P(axis) for _ in leaves),
+        out_specs=tuple(P(axis) for _ in range(2 * k)), check_vma=False)
     out = shard(coeffs, *leaves)
     mixed = jax.tree.unflatten(treedef, list(out[:k]))
     sent = jax.tree.unflatten(treedef, list(out[k:]))

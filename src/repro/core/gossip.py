@@ -37,19 +37,6 @@ from jax.sharding import PartitionSpec as P
 
 from . import graphs
 
-# jax.shard_map landed in newer releases (with check_vma); 0.4.x ships it as
-# jax.experimental.shard_map.shard_map (with check_rep).  Normalize both to
-# _shard_map(f, mesh, in_specs, out_specs) with replication checks off.
-if hasattr(jax, "shard_map"):
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _experimental_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, check_rep=False)
 
 __all__ = [
     "mix_stacked",
@@ -130,7 +117,11 @@ def mix_stacked(phi, tree):
 
     def _mix(leaf):
         flat = leaf.reshape(leaf.shape[0], -1)
-        mixed = phi.astype(leaf.dtype) @ flat
+        # HIGHEST: at default precision a TPU multiplies f32 operands in one
+        # bf16 pass, which moved the paper problem's consensus error by up
+        # to 5% on a v5e
+        mixed = jnp.matmul(phi.astype(leaf.dtype), flat,
+                           precision=jax.lax.Precision.HIGHEST)
         return mixed.reshape(leaf.shape)
 
     return jax.tree.map(_mix, tree)
@@ -350,8 +341,8 @@ def mix_stacked_permute(phi: PermutePhi, tree):
         return tuple(out)
 
     leaves, treedef = jax.tree.flatten(tree)
-    shard = _shard_map(
-        _local, mesh,
-        (P(None, axis),) + tuple(P(axis) for _ in leaves),
-        tuple(P(axis) for _ in leaves))
+    shard = jax.shard_map(
+        _local, mesh=mesh,
+        in_specs=(P(None, axis),) + tuple(P(axis) for _ in leaves),
+        out_specs=tuple(P(axis) for _ in leaves), check_vma=False)
     return jax.tree.unflatten(treedef, list(shard(coeffs, *leaves)))

@@ -116,7 +116,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import (algorithm as algorithm_lib, exec_spec as exec_spec_lib,
-               gossip, graphs, transport)
+               gossip, graphs, mesh as mesh_lib, transport)
 from .exec_spec import UNSET, ExecSpec
 
 __all__ = ["RunHistory", "RunResult", "Recorder", "run", "run_sweep",
@@ -914,7 +914,7 @@ def _node_shard_mesh(mesh, aux, m: int):
                 f"the {ndev} visible device(s), but m={m} is not divisible "
                 f"by the device count; pass mesh= with an axis whose size "
                 f"divides m")
-        return jax.make_mesh((ndev,), ("nodes",)), "nodes"
+        return mesh_lib.make_mesh((ndev,), ("nodes",)), "nodes"
     for axis, size in mesh.shape.items():
         if size and m % size == 0:
             return mesh, axis

@@ -68,7 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import (algorithm as algorithm_lib, exec_spec as exec_spec_lib,
-               transport)
+               mesh as mesh_lib, transport)
 from .exec_spec import UNSET, ExecSpec
 
 __all__ = ["SweepResult", "expand_grid", "run_sweep"]
@@ -382,7 +382,7 @@ def _cells_mesh(mesh, B: int):
     executes a contiguous grid slice)."""
     if mesh is None:
         ndev = len(jax.devices())
-        mesh = jax.make_mesh((ndev,), ("cells",))
+        mesh = mesh_lib.make_mesh((ndev,), ("cells",))
         axis, size = "cells", ndev
     else:
         size = dict(mesh.shape).get("cells")

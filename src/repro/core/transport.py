@@ -76,7 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import compression, gossip, graphs
+from . import compression, gossip, graphs, mesh as mesh_lib
 
 __all__ = [
     "TransportMeta",
@@ -404,16 +404,15 @@ class PPermuteBackend(GossipBackend):
     def prepare(self, schedule, meta, *, mesh=None):
         m = schedule.m
         if mesh is None:
-            devices = jax.devices()
-            if len(devices) < m:
+            ndev = len(jax.devices())
+            if ndev < m:
                 raise ValueError(
                     f"ppermute gossip needs a mesh with a node axis of size "
-                    f"{m}, but only {len(devices)} device(s) are visible "
+                    f"{m}, but only {ndev} device(s) are visible "
                     f"(force a host-platform mesh with XLA_FLAGS="
                     f"--xla_force_host_platform_device_count={m}, or pass "
                     f"mesh=)")
-            mesh = jax.make_mesh((m,), ("nodes",),
-                                 devices=np.array(devices[:m]))
+            mesh = mesh_lib.make_mesh((m,), ("nodes",))
             axis = "nodes"
         else:
             axis = _node_axis(mesh, m)

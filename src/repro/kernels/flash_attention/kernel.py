@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import compiler_params
 
 __all__ = ["flash_attention_call", "DEFAULT_BLOCK_Q", "DEFAULT_BLOCK_K"]
 
@@ -145,7 +144,8 @@ def flash_attention_call(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),    # running max m
             pltpu.VMEM((block_q, 1), jnp.float32),    # running sum l
         ],
-        compiler_params=compiler_params(("parallel", "parallel", "parallel",
-                                         "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
     )(q, k, v)

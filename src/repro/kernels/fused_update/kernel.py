@@ -23,8 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import compiler_params
 from . import ref
 
 __all__ = ["svrg_step_kernel_call", "mix_prox_kernel_call",
@@ -60,7 +60,8 @@ def _grid_call(kernel, scalars, operands, interpret: bool):
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct(operands[0].shape, operands[0].dtype),
         # elementwise over independent row blocks: fully parallel grid
-        compiler_params=compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(scalars, *operands)
 
@@ -133,6 +134,7 @@ def fused_step_kernel_call(w, streams, alpha, lam, *, m: int, rule: str,
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct(streams[0].shape, streams[0].dtype),
         # column tiles are independent: fully parallel grid
-        compiler_params=compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(scalars, w, *streams)

@@ -140,7 +140,7 @@ def _resolve_impl(impl: str) -> str:
         # Off-TPU the real kernel can't lower and interpret mode is far too
         # slow for a hot path; the jitted oracle IS the fused path there
         # (same math, one fused XLA computation).  interpret stays
-        # available explicitly for bitwise kernel-vs-ref tests.
+        # available explicitly for kernel-vs-ref tests.
         return "kernel" if jax.default_backend() == "tpu" else "ref"
     return impl
 

@@ -18,8 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import compiler_params
 
 __all__ = ["rmsnorm_kernel_call", "BLOCK_ROWS"]
 
@@ -49,6 +49,7 @@ def rmsnorm_kernel_call(x, weight, eps: float = 1e-6, *, interpret: bool):
         out_specs=pl.BlockSpec((BLOCK_ROWS, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         # per-row reduction only: the row grid is embarrassingly parallel
-        compiler_params=compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, weight)

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.mesh import make_mesh
 from repro.train.sharding import MeshPlan
 
 __all__ = ["make_production_mesh", "default_plan", "PLANS"]
@@ -27,15 +28,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = 1
     for s in shape:
         n *= s
-    devs = jax.devices()
-    if len(devs) == n:
-        return jax.make_mesh(shape, axes)
-    if len(devs) < n:
+    ndev = len(jax.devices())
+    if ndev < n:
         raise RuntimeError(
-            f"mesh {shape} needs {n} devices, found {len(devs)} — run under "
+            f"mesh {shape} needs {n} devices, found {ndev} — run under "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 (dry-run) "
             "or on the real slice")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return make_mesh(shape, axes)
 
 
 # DPSVRG node mappings (DESIGN.md §4):

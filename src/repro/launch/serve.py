@@ -15,7 +15,13 @@ mesh via repro.launch.dryrun):
     TTFT/TPOT percentiles + sustained tokens/s; without it, one fixed
     batch of prompts is served closed-loop,
   * prefill and decode are jitted and WARMED before any timing, so
-    reported ms excludes compile.
+    reported ms excludes compile,
+  * model: the smoke variant of ``--arch`` by default (CPU-sized);
+    ``--layers N`` keeps every published width and cuts only the depth,
+    ``--flash`` runs prefill attention through the Pallas flash kernel,
+  * ``--verify-host`` replays the closed-loop batch through the host
+    ``ContinuousBatcher`` with einsum attention and fails unless every
+    output token matches.
 
     PYTHONPATH=src python -m repro.launch.train --arch h2o-danube-1.8b \
         --steps 50 --ckpt-dir /tmp/run0
@@ -79,6 +85,14 @@ def main(argv=None):
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="h2o-danube-1.8b")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep the published widths and cut the depth to "
+                         "N layers (0 = the CPU-sized smoke variant)")
+    ap.add_argument("--flash", action="store_true",
+                    help="prefill attention through the flash kernel")
+    ap.add_argument("--verify-host", action="store_true",
+                    help="closed loop only: check every output against the "
+                         "host ContinuousBatcher with einsum attention")
     ap.add_argument("--ckpt-dir", default="",
                     help="load consensus params from a launch.train "
                          "checkpoint instead of random init")
@@ -101,7 +115,11 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    cfg = configs.smoke_variant(configs.get_config(args.arch))
+    cfg = configs.get_config(args.arch)
+    cfg = (configs.depth_variant(cfg, args.layers) if args.layers
+           else configs.smoke_variant(cfg))
+    if args.flash:
+        cfg = cfg.scaled(use_flash=True)
     if cfg.frontend != "none":
         raise SystemExit(f"{args.arch}: serve drives the token path; pick "
                          "a text arch (modality stubs: examples/serve_lm.py)")
@@ -119,7 +137,7 @@ def main(argv=None):
         backend = _build_backend(args, cfg, params)
         timings = stream_lib.replay(backend, requests)
         summary = metrics_lib.summarize(timings)
-        print(f"arch={args.arch} (smoke) engine={args.engine} "
+        print(f"arch={cfg.name} engine={args.engine} "
               f"slots={args.slots} stream={args.arrival}@{args.rate}/s "
               f"(warmup {t_warm*1e3:.0f} ms, untimed)")
         print(f"  {summary['requests']} requests, {summary['tokens']} "
@@ -136,26 +154,43 @@ def main(argv=None):
     t_warm = _warm(args, cfg, params, [args.prompt_len])
     backend = _build_backend(args, cfg, params)
     rng = np.random.default_rng(args.seed)
-    for uid in range(args.requests):
-        backend.submit(Request(
-            uid=uid, tokens=rng.integers(0, cfg.vocab_size,
-                                         size=args.prompt_len)
-            .astype(np.int32), max_new_tokens=args.new))
+    requests = [Request(uid=uid, tokens=rng.integers(
+        0, cfg.vocab_size, size=args.prompt_len).astype(np.int32),
+        max_new_tokens=args.new) for uid in range(args.requests)]
+    for req in requests:
+        backend.submit(req)
     t0 = time.perf_counter()
     while backend.busy:
         backend.step()
     span = time.perf_counter() - t0
     total = sum(len(v) for v in backend.outputs.values())
-    print(f"arch={args.arch} (smoke) engine={args.engine} "
+    print(f"arch={cfg.name} engine={args.engine} "
           f"slots={args.slots}: {args.requests} requests, {total} tokens "
           f"in {span*1e3:.1f} ms (warmup {t_warm*1e3:.0f} ms, untimed)")
     print(f"  {total/span:.1f} tok/s ({span*1e3/total:.3f} ms/tok)")
     sample = backend.outputs[0]
     print("sample:", np.asarray(sample)[:16].tolist())
+    if args.verify_host:
+        from repro.serve.scheduler import ContinuousBatcher
+        # einsum attention on the host side, so --flash is checked too
+        host = ContinuousBatcher(cfg.scaled(use_flash=False), params,
+                                 max_slots=args.slots, max_len=args.max_len)
+        for req in requests:
+            host.submit(req)
+        expect = host.run_until_done()
+        bad = [u for u in expect
+               if not np.array_equal(expect[u], backend.outputs[u])]
+        if bad:
+            raise SystemExit(f"outputs of requests {bad} differ from the "
+                             f"host ContinuousBatcher")
+        print(f"  all {len(expect)} outputs equal the host batcher's")
     return {"requests": args.requests, "tokens": total, "span_s": span,
             "tokens_per_s": total / span,
-            "ms_per_token": span * 1e3 / total}
+            "ms_per_token": span * 1e3 / total,
+            "outputs": dict(backend.outputs)}
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

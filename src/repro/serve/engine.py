@@ -76,8 +76,11 @@ def _build_executables(cfg: ModelConfig, max_len: int, eos: int | None,
     the serving analogue of ``runner``'s persistent executable cache.
     ``pick`` must be hashable (module functions are; ad-hoc lambdas get
     their own cache entries)."""
-    prefill = jax.jit(functools.partial(
-        transformer.prefill, cfg, max_len=max_len))
+    @jax.jit
+    def prefill(params, tokens, **kw):
+        return transformer.prefill(cfg, params, tokens, max_len=max_len,
+                                   **kw)
+
     decode = functools.partial(transformer.decode_step, cfg)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))

@@ -130,3 +130,24 @@ def test_long_context_applicability_flags():
         assert ok == (a in runs)
         if not ok:
             assert "full-attention" in reason
+
+
+@pytest.mark.parametrize("arch", configs.ARCHITECTURES)
+def test_depth_variant_keeps_published_widths(arch):
+    """``depth_variant`` (``launch.train``/``launch.serve --layers``) cuts
+    only the depth: every other field is the published config's."""
+    import dataclasses
+
+    cfg = configs.get_config(arch)
+    cut = configs.depth_variant(cfg, 1)
+    assert cut.num_layers == 1
+    same = {f.name for f in dataclasses.fields(cfg)} - {"name", "num_layers"}
+    assert {f: getattr(cut, f) for f in same} == \
+        {f: getattr(cfg, f) for f in same}
+    assert configs.depth_variant(cfg, cfg.num_layers) is cfg
+    params = jax.eval_shape(lambda: transformer.init_params(
+        cut, jax.random.PRNGKey(0)))
+    assert len(params["layers"]) == 1
+    for bad in (0, cfg.num_layers + 1):
+        with pytest.raises(ValueError):
+            configs.depth_variant(cfg, bad)

@@ -22,13 +22,14 @@ SCRIPT = textwrap.dedent("""
     src = os.environ["REPRO_SRC"]
     import sys; sys.path.insert(0, src)
     from repro.core import gossip, graphs
+    from repro.core.mesh import make_mesh
     from repro.train import sharding, steps as steps_lib
     from repro.core import prox as prox_lib
     from repro.models.api import ModelConfig
 
     out = {}
     m = 8
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     rng = np.random.default_rng(0)
 
     # 1) einsum gossip under jit+mesh == host numpy

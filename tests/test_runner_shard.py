@@ -31,6 +31,7 @@ _PRELUDE = textwrap.dedent("""
     from repro.core import algorithm, dpsvrg, gossip, graphs, prox, runner, \\
         sweep, transport
     from repro.core.exec_spec import ExecSpec
+    from repro.core.mesh import make_mesh
     from repro.data import synthetic
 
     def loss(w, batch):
@@ -119,7 +120,7 @@ _CELLS_TOPOLOGY_SCRIPT = _PRELUDE + textwrap.dedent("""
     plain = sweep.run_sweep(build, grid,
                             exec=ExecSpec(resident=True, gossip="dense"),
                             record_every=4, mode="zip")
-    mesh = jax.make_mesh((4,), ("cells",))
+    mesh = make_mesh((4,), ("cells",))
     sharded = sweep.run_sweep(
         build, grid,
         exec=ExecSpec(resident=True, gossip="dense", mesh=mesh,

@@ -107,12 +107,13 @@ def test_launch_serve_from_checkpoint(tmp_path, capsys):
     summary = launch_serve.main([
         "--arch", arch, "--ckpt-dir", ckpt, "--slots", "2",
         "--max-len", "48", "--requests", "3", "--prompt-len", "8",
-        "--new", "4"])
+        "--new", "4", "--verify-host"])
     assert summary["requests"] == 3 and summary["tokens"] == 3 * 4
     assert summary["tokens_per_s"] > 0
     out = capsys.readouterr().out
     assert "consensus ckpt step=2 m=4" in out
     assert "tok/s" in out
+    assert "all 3 outputs equal the host batcher's" in out
 
 
 def test_launch_serve_stream_mode(tmp_path):

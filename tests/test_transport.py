@@ -316,6 +316,7 @@ _PPERMUTE_SCRIPT = textwrap.dedent("""
     from repro.core import algorithm, dpsvrg, gossip, graphs, prox, runner, \\
         transport
     from repro.core.exec_spec import ExecSpec
+    from repro.core.mesh import make_mesh
     from repro.data import synthetic
 
     def loss(w, batch):
@@ -339,7 +340,7 @@ _PPERMUTE_SCRIPT = textwrap.dedent("""
     # is judged on the DSPG meta (one round/step): the m=4 matchings keep
     # offsets {0, 1, 3} — real band structure.  (DPSVRG's k_max=2 products
     # saturate all 4 offsets at m=4, so auto rightly picks dense there.)
-    mesh = jax.make_mesh((m,), ("nodes",))
+    mesh = make_mesh((m,), ("nodes",))
     hp2 = dpsvrg.DSPGHyperParams(alpha0=0.3)
     meta2 = algorithm.dspg_algorithm(problem, hp2, 24).meta
     out["auto_with_mesh"] = transport.select_backend_name(sched, meta2, mesh)
