@@ -37,8 +37,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from . import compression, gossip, prox as prox_lib, schedules, svrg, \
-    transport
+from . import compression, gossip, prox as prox_lib, schedules, spans, \
+    svrg, transport
 from ..kernels.fused_update import ops as fused_ops
 
 __all__ = [
@@ -160,9 +160,12 @@ def prox_gossip_update(params, v, phi, alpha, prox: prox_lib.Prox,
     serves every stateless transport backend; ``mix_fn`` stays pluggable for
     callers that need a bespoke collective.
     """
-    q = jax.tree.map(lambda x, vi: x - alpha * vi.astype(x.dtype), params, v)
+    with spans.scope(spans.UPDATE):
+        q = jax.tree.map(lambda x, vi: x - alpha * vi.astype(x.dtype),
+                         params, v)
     q_hat = mix_fn(phi, q)
-    return prox.apply(q_hat, alpha)
+    with spans.scope(spans.PROX):
+        return prox.apply(q_hat, alpha)
 
 
 # ---------------------------------------------------------------------------
@@ -177,8 +180,13 @@ def build_node_grad_fn(loss_fn: Callable) -> Callable:
     decentralized learning — under GSPMD the vmapped axis is the node mesh
     axis, so no cross-node communication happens here.
     """
-    g = jax.grad(loss_fn)
-    return jax.vmap(g)
+    g = jax.vmap(jax.grad(loss_fn))
+
+    def node_grad(params, batch):
+        with spans.scope(spans.GRAD):
+            return g(params, batch)
+
+    return node_grad
 
 
 def build_node_full_grad_fn(loss_fn: Callable, full_batch) -> Callable:
@@ -271,10 +279,12 @@ def build_dpsvrg_inner_step(loss_fn: Callable, prox: prox_lib.Prox,
                     not isinstance(phi, compression.CompressedPhi):
                 phi = compression.CompressedPhi(phi, compress_bits)
             v = svrg.corrected_gradient(node_grad, params, svrg_state, batch)
-            q = jax.tree.map(lambda x, vi: x - alpha * vi.astype(x.dtype),
-                             params, v)
+            with spans.scope(spans.UPDATE):
+                q = jax.tree.map(lambda x, vi: x - alpha * vi.astype(x.dtype),
+                                 params, v)
             q_hat, cstate = compression.mix_with_state(phi, q, cstate)
-            x = prox.apply(q_hat, alpha)
+            with spans.scope(spans.PROX):
+                x = prox.apply(q_hat, alpha)
             return x, cstate
 
         return step
@@ -313,9 +323,11 @@ def build_gt_svrg_inner_step(loss_fn: Callable, prox: prox_lib.Prox):
         @jax.jit
         def inner(params, tracker, v_prev, est, batch, w, a, cstate):
             cq, ct = cstate if cstate is not None else (None, None)
-            q = jax.tree.map(lambda x, y: x - a * y, params, tracker)
+            with spans.scope(spans.UPDATE):
+                q = jax.tree.map(lambda x, y: x - a * y, params, tracker)
             q_hat, cq = compression.mix_with_state(w, q, cq)
-            new_params = prox.apply(q_hat, a)
+            with spans.scope(spans.PROX):
+                new_params = prox.apply(q_hat, a)
             v_new = svrg.corrected_gradient(node_grad, new_params, est, batch)
             t_mixed, ct = compression.mix_with_state(w, tracker, ct)
             new_tracker = jax.tree.map(
@@ -355,12 +367,15 @@ def build_dvr_inner_step(loss_fn: Callable, prox: prox_lib.Prox, rho: float):
         @jax.jit
         def step(params, est, batch, phi, alpha, cstate):
             v = svrg.corrected_gradient(node_grad, params, est, batch)
-            y = jax.tree.map(lambda x, vi: x - alpha * vi.astype(x.dtype),
-                             params, v)
+            with spans.scope(spans.UPDATE):
+                y = jax.tree.map(lambda x, vi: x - alpha * vi.astype(x.dtype),
+                                 params, v)
             y_mixed, cstate = compression.mix_with_state(phi, y, cstate)
-            q = jax.tree.map(lambda a, b: (1.0 - rho) * a + rho * b,
-                             y, y_mixed)
-            return prox.apply(q, alpha), cstate
+            with spans.scope(spans.MIX):     # the damping is DVR's gossip
+                q = jax.tree.map(lambda a, b: (1.0 - rho) * a + rho * b,
+                                 y, y_mixed)
+            with spans.scope(spans.PROX):
+                return prox.apply(q, alpha), cstate
 
         return step
 
@@ -842,9 +857,11 @@ def dpg_algorithm(problem: Problem, alpha: float, num_steps: int) -> Algorithm:
     @jax.jit
     def _step(params, w, a):
         g = full_grad_fn(params)
-        q = jax.tree.map(lambda x, gi: x - a * gi, params, g)
+        with spans.scope(spans.UPDATE):
+            q = jax.tree.map(lambda x, gi: x - a * gi, params, g)
         q_hat = gossip.mix_stacked(w, q)
-        return prox.apply(q_hat, a)
+        with spans.scope(spans.PROX):
+            return prox.apply(q_hat, a)
 
     def step(state, batch, phi, alpha):
         return ParamState(_step(state.params, phi, alpha))

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from . import gossip
+from . import gossip, spans
 
 __all__ = ["CompressionState", "init_state", "quantize_leaf",
            "quantize_int_leaf", "compressed_mix", "compressed_mix_permute",
@@ -211,14 +211,15 @@ def mix_with_state(phi, tree, state: CompressionState | None):
     specialize per transport with zero runtime dispatch cost.  Types added
     via :func:`register_mix_handler` (scenario transports) dispatch first.
     """
-    handler = _MIX_HANDLERS.get(type(phi))
-    if handler is not None:
-        return handler(phi, tree, state)
-    if isinstance(phi, CompressedPhi):
-        if state is None:
-            raise ValueError(
-                "compressed gossip needs an error-feedback CompressionState; "
-                "the driven algorithm must thread a mix state "
-                "(see Algorithm.init_mix_state)")
-        return compressed_mix(phi.inner, tree, state, bits=phi.bits)
-    return gossip.mix_stacked(phi, tree), state
+    with spans.scope(spans.MIX):
+        handler = _MIX_HANDLERS.get(type(phi))
+        if handler is not None:
+            return handler(phi, tree, state)
+        if isinstance(phi, CompressedPhi):
+            if state is None:
+                raise ValueError(
+                    "compressed gossip needs an error-feedback "
+                    "CompressionState; the driven algorithm must thread a "
+                    "mix state (see Algorithm.init_mix_state)")
+            return compressed_mix(phi.inner, tree, state, bits=phi.bits)
+        return gossip.mix_stacked(phi, tree), state

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from . import graphs
+from . import graphs, spans
 
 
 __all__ = [
@@ -109,22 +109,23 @@ def mix_stacked(phi, tree):
             f"through compression.mix_with_state and thread a mix state "
             f"(Algorithm.init_mix_state) — only DPSVRG-family algorithms "
             f"do; dspg/dpg support stateless transports only")
-    if isinstance(phi, BandedPhi):
-        return mix_stacked_banded(phi.offsets, phi.coeffs, tree)
-    if isinstance(phi, PermutePhi):
-        return mix_stacked_permute(phi, tree)
-    phi = jnp.asarray(phi, dtype=jnp.float32)
+    with spans.scope(spans.MIX):
+        if isinstance(phi, BandedPhi):
+            return mix_stacked_banded(phi.offsets, phi.coeffs, tree)
+        if isinstance(phi, PermutePhi):
+            return mix_stacked_permute(phi, tree)
+        phi = jnp.asarray(phi, dtype=jnp.float32)
 
-    def _mix(leaf):
-        flat = leaf.reshape(leaf.shape[0], -1)
-        # HIGHEST: at default precision a TPU multiplies f32 operands in one
-        # bf16 pass, which moved the paper problem's consensus error by up
-        # to 5% on a v5e
-        mixed = jnp.matmul(phi.astype(leaf.dtype), flat,
-                           precision=jax.lax.Precision.HIGHEST)
-        return mixed.reshape(leaf.shape)
+        def _mix(leaf):
+            flat = leaf.reshape(leaf.shape[0], -1)
+            # HIGHEST: at default precision a TPU multiplies f32 operands in
+            # one bf16 pass, which moved the paper problem's consensus error
+            # by up to 5% on a v5e
+            mixed = jnp.matmul(phi.astype(leaf.dtype), flat,
+                               precision=jax.lax.Precision.HIGHEST)
+            return mixed.reshape(leaf.shape)
 
-    return jax.tree.map(_mix, tree)
+        return jax.tree.map(_mix, tree)
 
 
 def multi_consensus_matrix(schedule: graphs.MixingSchedule, t0: int, k: int,

@@ -37,7 +37,10 @@ Three execution paths:
   minibatches inside the compiled body — a different (but equally valid)
   sample stream, and nothing per-step ever leaves the device.
   ``RunResult.extras['transfers_h2d'/'transfers_d2h']`` reports the
-  driver-initiated transfer events for every path.
+  driver-initiated transfer events for every path, and
+  ``extras['bytes_h2d'/'bytes_d2h']`` the bytes they moved.  A resident
+  job marks its host phases (plan, stage, dispatch, pull) and its compiled
+  chunks their device work with the names of :mod:`repro.core.spans`.
 
 Gossip transports are pluggable (``gossip``, a :mod:`repro.core.transport`
 backend name or instance; default ``"auto"``):
@@ -116,7 +119,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import (algorithm as algorithm_lib, exec_spec as exec_spec_lib,
-               gossip, graphs, mesh as mesh_lib, transport)
+               gossip, graphs, mesh as mesh_lib, spans, transport)
 from .exec_spec import UNSET, ExecSpec
 
 __all__ = ["RunHistory", "RunResult", "Recorder", "run", "run_sweep",
@@ -406,9 +409,11 @@ def _make_record_kernel(problem, meta):
         @functools.partial(jax.jit, donate_argnums=0)
         def record(bufs, params, data):
             obj_buf, cons_buf, slot = bufs
-            obj_buf = obj_buf.at[slot].set(obj(params, data))
-            if track:
-                cons_buf = cons_buf.at[slot].set(traceable_consensus(params))
+            with spans.scope(spans.RECORD):
+                obj_buf = obj_buf.at[slot].set(obj(params, data))
+                if track:
+                    cons_buf = cons_buf.at[slot].set(
+                        traceable_consensus(params))
             return (obj_buf, cons_buf, slot + 1)
 
         return record
@@ -457,11 +462,13 @@ def _chunk_body(data, *, step_fn, meta, device_sampling: bool,
         first = jax.tree.leaves(data)[0]
         m, n = first.shape[0], first.shape[1]
 
-        def gather(idx):
-            return jax.tree.map(
-                lambda a: jnp.take_along_axis(
-                    a, idx.reshape(m, bsz, *([1] * (a.ndim - 2))),
-                    axis=1), data)
+        def sample(key):
+            with spans.scope(spans.SAMPLE):
+                idx = jax.random.randint(key, (m, bsz), 0, n)
+                return jax.tree.map(
+                    lambda a: jnp.take_along_axis(
+                        a, idx.reshape(m, bsz, *([1] * (a.ndim - 2))),
+                        axis=1), data)
 
     def apply_step(carry, batch, phi, alpha, keep):
         # padded steps (keep=False) skip the update entirely at runtime,
@@ -471,8 +478,7 @@ def _chunk_body(data, *, step_fn, meta, device_sampling: bool,
             def do(operand):
                 state, key = operand
                 key, sub = jax.random.split(key)
-                idx = jax.random.randint(sub, (m, bsz), 0, n)
-                return step_fn(state, gather(idx), phi, alpha), key
+                return step_fn(state, sample(sub), phi, alpha), key
 
             return jax.lax.cond(keep, do, lambda o: o, carry)
         return jax.lax.cond(
@@ -486,6 +492,10 @@ def _chunk_body(data, *, step_fn, meta, device_sampling: bool,
             state, key = carry
             return (jax.lax.cond(pred, fn, lambda s: s, state), key)
         return jax.lax.cond(pred, fn, lambda s: s, carry)
+
+    def snapshot(pred, carry):
+        with spans.scope(spans.SNAPSHOT):
+            return cond_state(pred, lambda s: outer_fn(s, data), carry)
 
     def body(carry, xs):
         if transitions:
@@ -501,10 +511,10 @@ def _chunk_body(data, *, step_fn, meta, device_sampling: bool,
                 phi, alpha, keep = xs
                 batch = None
         if has_opre:
-            carry = cond_state(o_pre, lambda s: outer_fn(s, data), carry)
+            carry = snapshot(o_pre, carry)
         carry = apply_step(carry, batch, phi, alpha, keep)
         if has_opost:
-            carry = cond_state(o_post, lambda s: outer_fn(s, data), carry)
+            carry = snapshot(o_post, carry)
         if has_end:
             carry = cond_state(e_post, lambda s: end_fn(s, e_k), carry)
         return carry, None
@@ -874,9 +884,45 @@ def _plan_resident(cells: "list[_PlanCell]", *, m: int, n: int,
                  opost_batched=opost_batched)
 
 
+def _nbytes(tree) -> int:
+    """Bytes of a pytree's leaves; a device array reports its own without
+    a copy."""
+    return sum(leaf.nbytes if hasattr(leaf, "nbytes")
+               else np.asarray(leaf).nbytes for leaf in jax.tree.leaves(tree))
+
+
 def _staged_bytes(chunks) -> int:
-    return sum(leaf.nbytes for c in chunks
-               for leaf in jax.tree.leaves(c.xs))
+    return _nbytes([c.xs for c in chunks])
+
+
+def _leaves_on(tree, device: bool) -> list:
+    """The leaves of ``tree`` that are (``device``) or are not device
+    arrays."""
+    return [leaf for leaf in jax.tree.leaves(tree)
+            if isinstance(leaf, jax.Array) == device]
+
+
+def _new_ledger() -> dict:
+    """The driver-initiated transfer ledger: events and their bytes, each
+    way."""
+    return {"h2d": 0, "d2h": 0, "bytes_h2d": 0, "bytes_d2h": 0}
+
+
+def _moved(transfers: dict, way: str, tree) -> int:
+    """Enter one transfer of ``tree`` (``way``: "h2d" or "d2h") in the
+    ledger; -> its bytes."""
+    nbytes = _nbytes(tree)
+    transfers[way] += 1
+    transfers["bytes_" + way] += nbytes
+    return nbytes
+
+
+def _ledger(transfers: dict) -> dict:
+    """The ledger as ``RunResult.extras`` columns."""
+    return {"transfers_h2d": transfers["h2d"],
+            "transfers_d2h": transfers["d2h"],
+            "bytes_h2d": transfers["bytes_h2d"],
+            "bytes_d2h": transfers["bytes_d2h"]}
 
 
 def _warn_staging(staged: int, cells: int = 1) -> None:
@@ -936,23 +982,28 @@ def _run_resident(algo, problem, backend, aux, rng, *, m: int,
     device_sampling = has_batch and sampling == "device"
     transitions = _resolve_transitions(algo, device_transitions)
 
+    run_id = spans.next_run()
+
     # one host copy of the dataset for index gathering (the scan path pays
     # the same once-per-run pull); device sampling skips it entirely
     if has_batch and sampling == "host":
-        if any(isinstance(leaf, jax.Array)
-               for leaf in jax.tree.leaves(problem.full_data)):
-            transfers["d2h"] += 1
-        host_data = jax.tree.map(np.asarray, problem.full_data)
+        pulled = _leaves_on(problem.full_data, True)
+        d2h = _moved(transfers, "d2h", pulled) if pulled else 0
+        with spans.span(spans.STAGE, run=run_id, h2d_bytes=0, d2h_bytes=d2h):
+            host_data = jax.tree.map(np.asarray, problem.full_data)
     else:
         host_data = None
     # the device PRNG seed is drawn from the run's rng stream, so
     # resident+device runs are reproducible from the same `seed`
     key_seed = int(rng.integers(0, 2**31 - 1)) if device_sampling else 0
 
-    plan = _plan_resident(
-        [_PlanCell(meta, rng, backend, aux)], m=m, n=n,
-        param_count=param_count, record_every=record_every,
-        sampling=sampling, host_data=host_data, transitions=transitions)
+    with spans.span(spans.PLAN, run=run_id) as plan_span:
+        plan = _plan_resident(
+            [_PlanCell(meta, rng, backend, aux)], m=m, n=n,
+            param_count=param_count, record_every=record_every,
+            sampling=sampling, host_data=host_data, transitions=transitions)
+        plan_span.set_metadata(steps=int(plan.cols["steps"][-1]),
+                               chunks=len(plan.chunks))
 
     exec_chunk = _make_resident_exec(algo, sampling, transitions, kernel)
     record_kernel = _make_record_kernel(problem, meta)
@@ -985,41 +1036,6 @@ def _run_resident(algo, problem, backend, aux, rng, *, m: int,
                     out.append(jax.tree.map(lambda l: rep, comp))
             return tuple(out)
 
-    # dataset staging only transfers when the problem holds host arrays
-    # (jnp.asarray on a committed device array is a no-op)
-    if any(not isinstance(leaf, jax.Array)
-           for leaf in jax.tree.leaves(problem.full_data)):
-        transfers["h2d"] += 1
-    if shard == "nodes":
-        data_dev = jax.device_put(problem.full_data,
-                                  jax.tree.map(_node_leaf,
-                                               problem.full_data))
-    else:
-        data_dev = jax.tree.map(jnp.asarray, problem.full_data)
-    # ONE staging transfer ships every chunk's xs (and nothing per-step
-    # thereafter); the shielded state copy protects caller-owned buffers
-    # (problem.x0) from the donated carries.  NOTE the memory trade:
-    # host-sampled batches for the WHOLE run live on device at once —
-    # O(num_steps * m * batch * feature) bytes; warn when that gets big
-    # (sampling="device" stages no batches at all)
-    _warn_staging(_staged_bytes(plan.chunks))
-    if shard == "nodes":
-        staged = jax.device_put([c.xs for c in plan.chunks],
-                                [_xs_shardings(c.xs) for c in plan.chunks])
-    else:
-        staged = jax.device_put([c.xs for c in plan.chunks])
-    transfers["h2d"] += 1
-
-    state = algo.init()
-    state = inject_mix_state(algo, backend, aux, state)
-    if transitions and algo.device_state is not None:
-        state = algo.device_state(state)
-    state = _shield_for_donation(state)
-    if shard == "nodes":
-        # splits the (m, ...) state leaves — including any error-feedback
-        # mix state, which thereby stays shard-local — over the node axis
-        state = jax.device_put(state, jax.tree.map(_node_leaf, state))
-
     def pack(state):
         if device_sampling:
             key = jax.random.PRNGKey(key_seed)
@@ -1034,36 +1050,73 @@ def _run_resident(algo, problem, backend, aux, rng, *, m: int,
     def repack(carry, state):
         return (state, carry[1]) if device_sampling else state
 
-    carry = pack(state)
-    bufs = (jnp.zeros(plan.num_records, jnp.float32),
-            jnp.zeros(plan.num_records, jnp.float32),
-            jnp.zeros((), jnp.int32))
-    if shard == "nodes":
-        # the record kernel mixes bufs with sharded params — colocate them
-        # on the mesh (replicated) so the jit sees one device set
-        bufs = jax.device_put(bufs, rep)
+    # dataset staging only transfers when the problem holds host arrays
+    # (jnp.asarray on a committed device array is a no-op).  ONE staging
+    # transfer ships every chunk's xs (and nothing per-step thereafter); the
+    # shielded state copy protects caller-owned buffers (problem.x0) from
+    # the donated carries.  NOTE the memory trade: host-sampled batches for
+    # the WHOLE run live on device at once — O(num_steps * m * batch *
+    # feature) bytes; warn when that gets big (sampling="device" stages no
+    # batches at all)
+    pushed = _leaves_on(problem.full_data, False)
+    h2d = _moved(transfers, "h2d", pushed) if pushed else 0
+    _warn_staging(_staged_bytes(plan.chunks))
+    h2d += _moved(transfers, "h2d", [c.xs for c in plan.chunks])
+    with spans.span(spans.STAGE, run=run_id, h2d_bytes=h2d, d2h_bytes=0):
+        if shard == "nodes":
+            data_dev = jax.device_put(problem.full_data,
+                                      jax.tree.map(_node_leaf,
+                                                   problem.full_data))
+            staged = jax.device_put(
+                [c.xs for c in plan.chunks],
+                [_xs_shardings(c.xs) for c in plan.chunks])
+        else:
+            data_dev = jax.tree.map(jnp.asarray, problem.full_data)
+            staged = jax.device_put([c.xs for c in plan.chunks])
+
+        state = algo.init()
+        state = inject_mix_state(algo, backend, aux, state)
+        if transitions and algo.device_state is not None:
+            state = algo.device_state(state)
+        state = _shield_for_donation(state)
+        if shard == "nodes":
+            # splits the (m, ...) state leaves — including any error-feedback
+            # mix state, which thereby stays shard-local — over the node axis
+            state = jax.device_put(state, jax.tree.map(_node_leaf, state))
+
+        carry = pack(state)
+        bufs = (jnp.zeros(plan.num_records, jnp.float32),
+                jnp.zeros(plan.num_records, jnp.float32),
+                jnp.zeros((), jnp.int32))
+        if shard == "nodes":
+            # the record kernel mixes bufs with sharded params — colocate
+            # them on the mesh (replicated) so the jit sees one device set
+            bufs = jax.device_put(bufs, rep)
 
     guard = _RESIDENT_DISPATCH_GUARD
-    for op in plan.ops:
-        kind = op[0]
-        if kind == "chunk":
-            with guard():
-                carry = exec_chunk(carry, staged[op[1]], data_dev)
-        elif kind == "record":
-            with guard():
-                bufs = record_kernel(bufs, algo.get_params(unpack(carry)),
-                                     data_dev)
-        elif kind == "outer":
-            carry = repack(carry, _unalias_for_donation(
-                algo.outer(unpack(carry))))
-        else:  # ("end_outer", K)
-            state = unpack(carry)
-            if algo.end_outer is not None:
-                state = algo.end_outer(state, op[1])
-            carry = repack(carry, _unalias_for_donation(state))
+    with spans.span(spans.DISPATCH, run=run_id, dispatches=len(plan.ops)):
+        for op in plan.ops:
+            kind = op[0]
+            if kind == "chunk":
+                with guard():
+                    carry = exec_chunk(carry, staged[op[1]], data_dev)
+            elif kind == "record":
+                with guard():
+                    bufs = record_kernel(bufs,
+                                         algo.get_params(unpack(carry)),
+                                         data_dev)
+            elif kind == "outer":
+                carry = repack(carry, _unalias_for_donation(
+                    algo.outer(unpack(carry))))
+            else:  # ("end_outer", K)
+                state = unpack(carry)
+                if algo.end_outer is not None:
+                    state = algo.end_outer(state, op[1])
+                carry = repack(carry, _unalias_for_donation(state))
 
-    objective, consensus, _ = jax.device_get(bufs)   # the ONE history pull
-    transfers["d2h"] += 1
+    d2h = _moved(transfers, "d2h", bufs)
+    with spans.span(spans.PULL, run=run_id, d2h_bytes=d2h):
+        objective, consensus, _ = jax.device_get(bufs)   # the ONE history pull
 
     history = RunHistory(
         objective=np.asarray(objective, np.float64),
@@ -1071,9 +1124,7 @@ def _run_resident(algo, problem, backend, aux, rng, *, m: int,
         epochs=plan.cols["epochs"],
         comm_rounds=plan.cols["comm_rounds"],
         steps=plan.cols["steps"])
-    extras = {"wire_bytes": plan.wire,
-              "transfers_h2d": transfers["h2d"],
-              "transfers_d2h": transfers["d2h"]}
+    extras = {"wire_bytes": plan.wire, **_ledger(transfers)}
     return RunResult(params=algo.get_params(unpack(carry)), history=history,
                      extras=extras)
 
@@ -1236,9 +1287,10 @@ def run(algo: algorithm_lib.Algorithm,
     n = jax.tree.leaves(problem.full_data)[0].shape[1]
     param_count = transport.node_param_count(problem.x0)
     # driver-initiated host<->device transfer EVENTS (coarse: one per staged
-    # tree / per metric pull, not per buffer) — the resident path's O(1)
-    # claim is asserted against these in benchmarks and tests
-    transfers = {"h2d": 0, "d2h": 0}
+    # tree / per metric pull, not per buffer) and the bytes they move — the
+    # resident path's O(1) claim is asserted against these in benchmarks
+    # and tests
+    transfers = _new_ledger()
 
     if resident:
         return _run_resident(algo, problem, backend, aux, rng,
@@ -1257,9 +1309,9 @@ def run(algo: algorithm_lib.Algorithm,
     # sample minibatches from a host-side copy: per-step np gathers on device
     # arrays would silently round-trip the whole dataset every step
     if meta.batch_size > 0:
-        if any(isinstance(leaf, jax.Array)
-               for leaf in jax.tree.leaves(problem.full_data)):
-            transfers["d2h"] += 1
+        pulled = _leaves_on(problem.full_data, True)
+        if pulled:
+            _moved(transfers, "d2h", pulled)
         host_data = jax.tree.map(np.asarray, problem.full_data)
     else:
         host_data = problem.full_data
@@ -1285,8 +1337,9 @@ def run(algo: algorithm_lib.Algorithm,
         return phi
 
     def device_phi(phi):
-        transfers["h2d"] += 1
-        return jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), phi)
+        phi = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), phi)
+        _moved(transfers, "h2d", phi)
+        return phi
 
     def pad_chunk(batches, phis, alphas, chunk):
         """Pad collected inputs to the bucket length with masked-out repeats
@@ -1300,16 +1353,18 @@ def run(algo: algorithm_lib.Algorithm,
             alphas.extend(alphas[-1:] * pad)
         return [True] * chunk + [False] * pad
 
-    def do_record(params=None):
-        transfers["d2h"] += 1 + (1 if meta.track_consensus else 0)
-        rec.record(params if params is not None else algo.get_params(state),
-                   t=t, grad_evals=grad_evals, comm_rounds=comm,
+    def do_record():
+        params = algo.get_params(state)
+        _moved(transfers, "d2h", np.float32(0))      # the objective
+        if meta.track_consensus:
+            _moved(transfers, "d2h", params)         # every node's iterate
+        rec.record(params, t=t, grad_evals=grad_evals, comm_rounds=comm,
                    wire_bytes=wire)
 
     def run_chunk(state, batches, phis, alphas, keep):
-        transfers["h2d"] += 1
-        return exec_chunk(state, _stack_inputs(meta, batches, phis, alphas,
-                                               keep))
+        xs = _stack_inputs(meta, batches, phis, alphas, keep)
+        _moved(transfers, "h2d", xs)
+        return exec_chunk(state, xs)
 
     do_record()
 
@@ -1346,7 +1401,7 @@ def run(algo: algorithm_lib.Algorithm,
                     batch = (sample_batch(rng, host_data, meta.batch_size)
                              if meta.batch_size > 0 else None)
                     if meta.batch_size > 0:
-                        transfers["h2d"] += 1
+                        _moved(transfers, "h2d", batch)
                     phi = device_phi(phi_for(meta.gossip_rounds(k)))
                     state = algo.step(state, batch, phi,
                                       jnp.float32(meta.stepsize(t)))
@@ -1400,7 +1455,7 @@ def run(algo: algorithm_lib.Algorithm,
                 batch = (sample_batch(rng, host_data, meta.batch_size)
                          if meta.batch_size > 0 else None)
                 if meta.batch_size > 0:
-                    transfers["h2d"] += 1
+                    _moved(transfers, "h2d", batch)
                 phi = device_phi(phi_for(meta.gossip_rounds(t)))
                 state = algo.step(state, batch, phi,
                                   jnp.float32(meta.stepsize(t)))
@@ -1413,9 +1468,7 @@ def run(algo: algorithm_lib.Algorithm,
             if t % record_every == 0 or t == num_steps:
                 do_record()
 
-    extras = rec.extras()
-    extras["transfers_h2d"] = transfers["h2d"]
-    extras["transfers_d2h"] = transfers["d2h"]
+    extras = {**rec.extras(), **_ledger(transfers)}
     return RunResult(params=algo.get_params(state), history=rec.history(),
                      extras=extras)
 

@@ -24,6 +24,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from . import spans
+
 __all__ = ["SvrgState", "init_snapshot", "corrected_gradient", "tree_sub",
            "tree_add", "tree_axpy", "tree_dot", "tree_norm"]
 
@@ -79,5 +81,6 @@ def corrected_gradient(grad_fn: Callable, params, state: SvrgState, batch):
     """
     g_now = grad_fn(params, batch)
     g_snap = grad_fn(state.snapshot, batch)
-    return jax.tree.map(lambda a, b, mu: a - b + mu,
-                        g_now, g_snap, state.full_grad)
+    with spans.scope(spans.UPDATE):
+        return jax.tree.map(lambda a, b, mu: a - b + mu,
+                            g_now, g_snap, state.full_grad)

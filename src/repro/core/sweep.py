@@ -68,7 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import (algorithm as algorithm_lib, exec_spec as exec_spec_lib,
-               mesh as mesh_lib, transport)
+               mesh as mesh_lib, spans, transport)
 from .exec_spec import UNSET, ExecSpec
 
 __all__ = ["SweepResult", "expand_grid", "run_sweep"]
@@ -110,7 +110,8 @@ class SweepResult(NamedTuple):
     ``grid`` is the expanded cell list (reserved axes included).
     ``extras['wire_bytes']`` is ``(records, cells)``;
     ``extras['transfers_h2d'/'transfers_d2h']`` count driver-initiated
-    transfer events for the WHOLE sweep (O(1) on the batched path)."""
+    transfer events for the WHOLE sweep (O(1) on the batched path), and
+    ``extras['bytes_h2d'/'bytes_d2h']`` the bytes they moved."""
 
     grid: list
     params: Any
@@ -354,11 +355,12 @@ def _make_sweep_record(template, build, cache_key):
                 obj = runner_lib._resolved_objective(algo_t.meta, problem_t)
                 return obj(p, data)
 
-            vals = jax.vmap(one_cell, in_axes=(0, 0))(params, cells)
-            obj_buf = obj_buf.at[slot].set(vals)
-            if track:
-                cons = jax.vmap(runner_lib.traceable_consensus)(params)
-                cons_buf = cons_buf.at[slot].set(cons)
+            with spans.scope(spans.RECORD):
+                vals = jax.vmap(one_cell, in_axes=(0, 0))(params, cells)
+                obj_buf = obj_buf.at[slot].set(vals)
+                if track:
+                    cons = jax.vmap(runner_lib.traceable_consensus)(params)
+                    cons_buf = cons_buf.at[slot].set(cons)
             return (obj_buf, cons_buf, slot + 1)
 
         return functools.partial(jax.jit, donate_argnums=0)(record_impl)
@@ -565,12 +567,12 @@ def run_sweep(build: Callable,
     param_count = transport.node_param_count(template_problem.x0)
     has_batch = meta0.batch_size > 0
     device_sampling = has_batch and sampling == "device"
-    transfers = {"h2d": 0, "d2h": 0}
+    transfers = runner_lib._new_ledger()
 
     if has_batch and sampling == "host":
-        if any(isinstance(leaf, jax.Array)
-               for leaf in jax.tree.leaves(template_problem.full_data)):
-            transfers["d2h"] += 1
+        pulled = runner_lib._leaves_on(template_problem.full_data, True)
+        if pulled:
+            runner_lib._moved(transfers, "d2h", pulled)
         host_data = jax.tree.map(np.asarray, template_problem.full_data)
     else:
         host_data = None
@@ -628,22 +630,24 @@ def run_sweep(build: Callable,
 
     # one dataset staging (shared across cells) + ONE staging transfer for
     # every chunk's xs and the cell-axis hyperparameter arrays
-    if any(not isinstance(leaf, jax.Array)
-           for leaf in jax.tree.leaves(template_problem.full_data)):
-        transfers["h2d"] += 1
+    pushed = runner_lib._leaves_on(template_problem.full_data, False)
+    if pushed:
+        runner_lib._moved(transfers, "h2d", pushed)
     data_dev = jax.tree.map(jnp.asarray, template_problem.full_data)
     if shard == "cells":
         data_dev = _put_cells(data_dev, rep)
     runner_lib._warn_staging(runner_lib._staged_bytes(plan.chunks), cells=B)
+    cell_arrays = _cell_arrays(cells, axis_names)
+    runner_lib._moved(transfers, "h2d", ([c.xs for c in plan.chunks],
+                                         cell_arrays))
     if shard == "cells":
         staged, cells_dev = jax.device_put(
-            ([c.xs for c in plan.chunks], _cell_arrays(cells, axis_names)),
+            ([c.xs for c in plan.chunks], cell_arrays),
             ([_xs_shardings(c.xs) for c in plan.chunks],
              {name: cell0 for name in axis_names}))
     else:
         staged, cells_dev = jax.device_put(
-            ([c.xs for c in plan.chunks], _cell_arrays(cells, axis_names)))
-    transfers["h2d"] += 1
+            ([c.xs for c in plan.chunks], cell_arrays))
 
     states = []
     for (algo, _), aux in zip(built, auxes):
@@ -687,8 +691,8 @@ def run_sweep(build: Callable,
                 bufs = record_kernel(bufs, get_params(unpack(carry)),
                                      data_dev, cells_dev)
 
+    runner_lib._moved(transfers, "d2h", bufs)
     objective, consensus, _ = jax.device_get(bufs)   # the ONE history pull
-    transfers["d2h"] += 1
 
     history = runner_lib.RunHistory(
         objective=np.asarray(objective, np.float64),
@@ -696,9 +700,7 @@ def run_sweep(build: Callable,
         epochs=plan.cols["epochs"],
         comm_rounds=plan.cols["comm_rounds"],
         steps=plan.cols["steps"])
-    extras = {"wire_bytes": plan.wire,
-              "transfers_h2d": transfers["h2d"],
-              "transfers_d2h": transfers["d2h"]}
+    extras = {"wire_bytes": plan.wire, **runner_lib._ledger(transfers)}
     return SweepResult(grid=cells, params=get_params(unpack(carry)),
                        history=history, extras=extras)
 
@@ -723,10 +725,9 @@ def _run_sequential(built, cells, schedules, seeds, *, record_every,
     extras = {
         "wire_bytes": np.stack(
             [np.asarray(r.extras["wire_bytes"]) for r in results], axis=1),
-        "transfers_h2d": sum(int(r.extras["transfers_h2d"])
-                             for r in results),
-        "transfers_d2h": sum(int(r.extras["transfers_d2h"])
-                             for r in results),
+        **{k: sum(int(r.extras[k]) for r in results)
+           for k in ("transfers_h2d", "transfers_d2h", "bytes_h2d",
+                     "bytes_d2h")},
     }
     params = jax.tree.map(lambda *ls: jnp.stack(ls),
                           *[r.params for r in results])

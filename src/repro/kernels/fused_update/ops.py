@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...core import spans
 from . import kernel, ref
 
 __all__ = ["svrg_step", "mix_prox", "flatten_tree", "unflatten_tree",
@@ -174,12 +175,13 @@ def fused_resident_step(w, x_tree, grad_trees, alpha, lam, *, rule: str,
     same stacked (m, ...) structure as ``x_tree``.
     """
     m = jax.tree.leaves(x_tree)[0].shape[0]
-    x_buf, aux = flatten_stacked(x_tree, m)
-    streams = [x_buf] + [flatten_stacked(t, m)[0] for t in grad_trees]
-    w_pad = pad_mix_matrix(w, x_buf.shape[0])
-    out = fused_step_buf(w_pad, streams, alpha, lam, m=m, rule=rule,
-                         prox_kind=prox_kind, impl=impl)
-    return unflatten_stacked(out, aux)
+    with spans.scope(spans.FUSED_UPDATE):
+        x_buf, aux = flatten_stacked(x_tree, m)
+        streams = [x_buf] + [flatten_stacked(t, m)[0] for t in grad_trees]
+        w_pad = pad_mix_matrix(w, x_buf.shape[0])
+        out = fused_step_buf(w_pad, streams, alpha, lam, m=m, rule=rule,
+                             prox_kind=prox_kind, impl=impl)
+        return unflatten_stacked(out, aux)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -27,7 +27,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import algorithm as algo_lib, compression, gossip, \
-    prox as prox_lib, svrg
+    prox as prox_lib, spans, svrg
 from repro.models import transformer
 from repro.models.api import ModelConfig
 from . import sharding
@@ -145,10 +145,15 @@ def _build_train_step(cfg, prox, m, plan, mesh, rule,
     grad_only = jax.vmap(jax.grad(loss))
 
     def train_step(state: TrainState, batch, phi, alpha):
-        losses, g_now = vgrad(state.params, batch)
-        g_snap = grad_only(state.snapshot, batch) if rule.needs_snapshot \
-            else None
-        v = rule.direction(g_now, g_snap, state.full_grad)
+        with spans.scope(spans.GRAD):
+            losses, g_now = vgrad(state.params, batch)
+            g_snap = grad_only(state.snapshot, batch) \
+                if rule.needs_snapshot else None
+        with spans.scope(spans.UPDATE):
+            v = rule.direction(g_now, g_snap, state.full_grad)
+            # XLA fuses the norm's reductions with the update's tree
+            # arithmetic; the fusion takes the name of its root, the norm
+            v_norm = svrg.tree_norm(v)
 
         # the mix threads the transport state (compressed error feedback,
         # scenario delay FIFOs) via the dispatching mix_with_state; for
@@ -165,14 +170,15 @@ def _build_train_step(cfg, prox, m, plan, mesh, rule,
                                                  prox, mix_fn=mix_fn)
         metrics = {
             "loss": jnp.mean(losses),
-            "v_norm": svrg.tree_norm(v),
+            "v_norm": v_norm,
         }
         return state._replace(params=new_params, step=state.step + 1,
                               mix_state=mix_out["state"]), metrics
 
     def snapshot_step(state: TrainState, big_batch):
         """Outer loop: refresh snapshot + (large-batch) full local gradient."""
-        mu = grad_only(state.params, big_batch)
+        with spans.scope(spans.SNAPSHOT):
+            mu = grad_only(state.params, big_batch)
         return state._replace(snapshot=state.params, full_grad=mu)
 
     def init_state(rng):

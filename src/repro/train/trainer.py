@@ -58,7 +58,7 @@ import numpy as np
 from repro import checkpoint as ckpt_lib
 from repro.core import algorithm as algo_lib, \
     exec_spec as exec_spec_lib, graphs, prox as prox_lib, \
-    runner as runner_lib, schedules, sweep as sweep_lib, transport
+    runner as runner_lib, schedules, spans, sweep as sweep_lib, transport
 from repro.core.exec_spec import UNSET, ExecSpec
 from repro.data import loader as loader_lib
 from repro.models.api import ModelConfig
@@ -163,11 +163,12 @@ def _make_lm_exec(bundle, *, vr: bool, sampling: str, seq_len: int,
         L = seq_len
 
         def gather(shards, starts):
-            win = jax.vmap(
-                lambda row, st: row[st[:, None]
-                                    + jnp.arange(L + 1)[None, :]])(shards,
-                                                                   starts)
-            return {"tokens": win[..., :L], "labels": win[..., 1:]}
+            with spans.scope(spans.SAMPLE):
+                win = jax.vmap(
+                    lambda row, st: row[st[:, None]
+                                        + jnp.arange(L + 1)[None, :]])(
+                                            shards, starts)
+                return {"tokens": win[..., :L], "labels": win[..., 1:]}
 
         @functools.partial(jax.jit, donate_argnums=0)
         def exec_chunk(carry, xs, shards):
@@ -190,17 +191,19 @@ def _make_lm_exec(bundle, *, vr: bool, sampling: str, seq_len: int,
                                 k2, (m, snap_batch), 0, hi)
                             return snapshot_step(s, gather(shards, sstarts))
 
-                        state = jax.lax.cond(snap, do_snap, lambda s: s,
-                                             state)
+                        with spans.scope(spans.SNAPSHOT):
+                            state = jax.lax.cond(snap, do_snap, lambda s: s,
+                                                 state)
                 else:
                     state = carry
                     if vr:
                         starts, sstarts, snap, phi, alpha = xs
-                        state = jax.lax.cond(
-                            snap,
-                            lambda s: snapshot_step(s,
-                                                    gather(shards, sstarts)),
-                            lambda s: s, state)
+                        with spans.scope(spans.SNAPSHOT):
+                            state = jax.lax.cond(
+                                snap,
+                                lambda s: snapshot_step(
+                                    s, gather(shards, sstarts)),
+                                lambda s: s, state)
                     else:
                         starts, phi, alpha = xs
                 state, mets = train_step(state, gather(shards, starts), phi,
@@ -326,17 +329,21 @@ def train_loop(cfg: ModelConfig,
         if device_sampling else None
     param_count = transport.node_param_count(state.params)
 
-    transfers = {"h2d": 0, "d2h": 0}
+    transfers = runner_lib._new_ledger()
+    run_id = spans.next_run()
     start_step, slot, wire = 0, 0, 0
     if resume:
         template = {"state": state}
         if device_sampling:
             template["key"] = key
         tree, _, md = ckpt_lib.restore(tc.ckpt_dir, template)
-        state = jax.tree.map(jnp.asarray, tree["state"])
-        if device_sampling:
-            key = jnp.asarray(tree["key"])
-        transfers["h2d"] += 1
+        restored = [tree["state"], tree["key"]] if device_sampling \
+            else [tree["state"]]
+        h2d = runner_lib._moved(transfers, "h2d", restored)
+        with spans.span(spans.STAGE, run=run_id, h2d_bytes=h2d, d2h_bytes=0):
+            state = jax.tree.map(jnp.asarray, tree["state"])
+            if device_sampling:
+                key = jnp.asarray(tree["key"])
         start_step = int(md["step"])
         slot = int(md["slot"])
         wire = int(md["wire"])
@@ -370,19 +377,21 @@ def train_loop(cfg: ModelConfig,
         defaults suffice; the resident path plans (and thus advances all
         three to end-of-run) before executing, so its periodic saves pass
         the per-chunk boundary values explicitly."""
-        tree = {"state": jax.device_get(cur_state)}
-        if device_sampling:
-            tree["key"] = jax.device_get(cur_key)
-        transfers["d2h"] += 1
-        if loader_state is None and is_loader:
-            loader_state = data.state_dict()
-        md = {"step": next_step,
-              "slot": slot if slot_at is None else slot_at,
-              "wire": wire if wire_at is None else wire_at,
-              "algorithm": rule.name,
-              "loader": loader_state}
-        ckpt_lib.save(tc.ckpt_dir, next_step, tree, md,
-                      keep_last=tc.keep_last)
+        live = [cur_state, cur_key] if device_sampling else [cur_state]
+        d2h = runner_lib._moved(transfers, "d2h", live)
+        with spans.span(spans.CKPT, run=run_id, d2h_bytes=d2h):
+            tree = {"state": jax.device_get(cur_state)}
+            if device_sampling:
+                tree["key"] = jax.device_get(cur_key)
+            if loader_state is None and is_loader:
+                loader_state = data.state_dict()
+            md = {"step": next_step,
+                  "slot": slot if slot_at is None else slot_at,
+                  "wire": wire if wire_at is None else wire_at,
+                  "algorithm": rule.name,
+                  "loader": loader_state}
+            ckpt_lib.save(tc.ckpt_dir, next_step, tree, md,
+                          keep_last=tc.keep_last)
 
     # ------------------------------------------------------------------
     # host loop
@@ -406,20 +415,25 @@ def train_loop(cfg: ModelConfig,
             next_big = lambda: next(snap_it)
 
         for step in range(start_step, tc.num_steps):
+            staged = []
             if vr and step % tc.snapshot_every == 0:
                 big = jax.tree.map(jnp.asarray, next_big())
+                staged.append(big)
                 state = bundle.snapshot_step(state, big)
             batch = jax.tree.map(jnp.asarray, next_batch())
             phi = backend.phi_for(gaux, slot, tc.consensus_rounds)
             wire += backend.bytes_per_step(gaux, phi, param_count)
             slot += tc.consensus_rounds
-            transfers["h2d"] += 1      # per-step batch/phi staging
+            phi = _to_device_floats(phi)
+            # per-step batch/phi (and snapshot batch) staging
+            runner_lib._moved(transfers, "h2d", staged + [batch, phi])
             alpha = alpha_fn(step)
             state, metrics = bundle.train_step(
-                state, batch, _to_device_floats(phi), jnp.float32(alpha))
+                state, batch, phi, jnp.float32(alpha))
             if is_record(step):
                 record(step, metrics["loss"], metrics["v_norm"], alpha, wire)
-                transfers["d2h"] += 1
+                runner_lib._moved(transfers, "d2h",
+                                  [metrics["loss"], metrics["v_norm"]])
             if is_ckpt(step):
                 save_ckpt(state, None, step + 1)
     # ------------------------------------------------------------------
@@ -430,58 +444,64 @@ def train_loop(cfg: ModelConfig,
         snap_B = B * tc.snapshot_batch_mult
         host_sampling = not device_sampling
 
-        chunks: list[_LMChunk] = []
-        cur: dict[str, list] = {k: [] for k in
-                                ("starts", "sstarts", "snaps", "phis",
-                                 "alphas")}
-        alpha = 0.0
-        for step in range(start_step, tc.num_steps):
-            snap = vr and step % tc.snapshot_every == 0
-            if host_sampling:
-                if vr:
-                    # draw order matches the host loop exactly: snapshot
-                    # windows first (when refreshing), then the minibatch
-                    cur["sstarts"].append(
-                        data.sample_starts(snap_B) if snap
-                        else np.zeros((m, snap_B), np.int64))
-                cur["starts"].append(data.sample_starts(B))
-            if vr:
-                cur["snaps"].append(snap)
-            phi = backend.phi_for(gaux, slot, tc.consensus_rounds)
-            wire += backend.bytes_per_step(gaux, phi, param_count)
-            slot += tc.consensus_rounds
-            cur["phis"].append(phi)
-            alpha = alpha_fn(step)
-            cur["alphas"].append(alpha)
-            if is_record(step) or is_ckpt(step) or step == tc.num_steps - 1:
-                phis = jax.tree.map(lambda *l: runner_lib._stack_wire(l),
-                                    *cur["phis"])
-                alphas = np.asarray(cur["alphas"], np.float32)
+        with spans.span(spans.PLAN, run=run_id) as plan_span:
+            chunks: list[_LMChunk] = []
+            cur: dict[str, list] = {k: [] for k in
+                                    ("starts", "sstarts", "snaps", "phis",
+                                     "alphas")}
+            alpha = 0.0
+            for step in range(start_step, tc.num_steps):
+                snap = vr and step % tc.snapshot_every == 0
                 if host_sampling:
-                    starts = np.stack(cur["starts"]).astype(np.int32)
                     if vr:
-                        sstarts = np.stack(cur["sstarts"]).astype(np.int32)
-                        xs = (starts, sstarts,
-                              np.asarray(cur["snaps"], np.bool_), phis,
-                              alphas)
+                        # draw order matches the host loop exactly: snapshot
+                        # windows first (when refreshing), then the minibatch
+                        cur["sstarts"].append(
+                            data.sample_starts(snap_B) if snap
+                            else np.zeros((m, snap_B), np.int64))
+                    cur["starts"].append(data.sample_starts(B))
+                if vr:
+                    cur["snaps"].append(snap)
+                phi = backend.phi_for(gaux, slot, tc.consensus_rounds)
+                wire += backend.bytes_per_step(gaux, phi, param_count)
+                slot += tc.consensus_rounds
+                cur["phis"].append(phi)
+                alpha = alpha_fn(step)
+                cur["alphas"].append(alpha)
+                if is_record(step) or is_ckpt(step) \
+                        or step == tc.num_steps - 1:
+                    phis = jax.tree.map(lambda *l: runner_lib._stack_wire(l),
+                                        *cur["phis"])
+                    alphas = np.asarray(cur["alphas"], np.float32)
+                    if host_sampling:
+                        starts = np.stack(cur["starts"]).astype(np.int32)
+                        if vr:
+                            sstarts = np.stack(
+                                cur["sstarts"]).astype(np.int32)
+                            xs = (starts, sstarts,
+                                  np.asarray(cur["snaps"], np.bool_), phis,
+                                  alphas)
+                        else:
+                            xs = (starts, phis, alphas)
                     else:
-                        xs = (starts, phis, alphas)
-                else:
-                    xs = ((np.asarray(cur["snaps"], np.bool_), phis, alphas)
-                          if vr else (phis, alphas))
-                # ckpt boundaries snapshot the loader cursor HERE: at this
-                # point planning has drawn exactly the starts the host loop
-                # would have consumed through `step`, which is the cursor a
-                # mid-run resume must restore (the live state_dict after
-                # planning completes is the END-of-run cursor)
-                chunks.append(_LMChunk(
-                    xs=xs, length=len(cur["alphas"]), last_step=step,
-                    record=is_record(step),
-                    ckpt_next=step + 1 if is_ckpt(step) else None,
-                    alpha_last=alpha, wire_end=wire, slot_end=slot,
-                    loader_state=data.state_dict() if is_ckpt(step)
-                    else None))
-                cur = {k: [] for k in cur}
+                        snaps = np.asarray(cur["snaps"], np.bool_)
+                        xs = (snaps, phis, alphas) if vr else (phis, alphas)
+                    # ckpt boundaries snapshot the loader cursor HERE: at
+                    # this point planning has drawn exactly the starts the
+                    # host loop would have consumed through `step`, which is
+                    # the cursor a mid-run resume must restore (the live
+                    # state_dict after planning completes is the END-of-run
+                    # cursor)
+                    chunks.append(_LMChunk(
+                        xs=xs, length=len(cur["alphas"]), last_step=step,
+                        record=is_record(step),
+                        ckpt_next=step + 1 if is_ckpt(step) else None,
+                        alpha_last=alpha, wire_end=wire, slot_end=slot,
+                        loader_state=data.state_dict() if is_ckpt(step)
+                        else None))
+                    cur = {k: [] for k in cur}
+            plan_span.set_metadata(steps=tc.num_steps - start_step,
+                                   chunks=len(chunks))
 
         exec_chunk = _make_lm_exec(bundle, vr=vr, sampling=sampling,
                                    seq_len=data.seq_len, batch=B,
@@ -490,29 +510,30 @@ def train_loop(cfg: ModelConfig,
         # ONE staging transfer ships every chunk's xs plus the resident
         # token-shard buffer; nothing per-step crosses the host boundary
         # thereafter
-        staged_bytes = sum(leaf.nbytes for ch in chunks
-                           for leaf in jax.tree.leaves(ch.xs))
-        runner_lib._warn_staging(staged_bytes)
-        staged, shards_dev = jax.device_put(
-            ([ch.xs for ch in chunks], data.stacked_shards()))
-        transfers["h2d"] += 1
-
-        state = runner_lib._shield_for_donation(state)
+        xs_all = [ch.xs for ch in chunks]
+        runner_lib._warn_staging(runner_lib._nbytes(xs_all))
+        shards = data.stacked_shards()
+        h2d = runner_lib._moved(transfers, "h2d", (xs_all, shards))
+        with spans.span(spans.STAGE, run=run_id, h2d_bytes=h2d, d2h_bytes=0):
+            staged, shards_dev = jax.device_put((xs_all, shards))
+            state = runner_lib._shield_for_donation(state)
         carry = (state, key) if device_sampling else state
-        for i, ch in enumerate(chunks):
-            with runner_lib._RESIDENT_DISPATCH_GUARD():
-                carry, ys = exec_chunk(carry, staged[i], shards_dev)
-            if ch.record:
-                losses, vnorms = jax.device_get(ys)   # one pull per window
-                transfers["d2h"] += 1
-                record(ch.last_step, losses[ch.length - 1],
-                       vnorms[ch.length - 1], ch.alpha_last, ch.wire_end)
-            if ch.ckpt_next is not None:
-                cur_state, cur_key = (carry if device_sampling
-                                      else (carry, None))
-                save_ckpt(cur_state, cur_key, ch.ckpt_next,
-                          slot_at=ch.slot_end, wire_at=ch.wire_end,
-                          loader_state=ch.loader_state)
+        with spans.span(spans.DISPATCH, run=run_id, dispatches=len(chunks)):
+            for i, ch in enumerate(chunks):
+                with runner_lib._RESIDENT_DISPATCH_GUARD():
+                    carry, ys = exec_chunk(carry, staged[i], shards_dev)
+                if ch.record:
+                    d2h = runner_lib._moved(transfers, "d2h", ys)
+                    with spans.span(spans.PULL, run=run_id, d2h_bytes=d2h):
+                        losses, vnorms = jax.device_get(ys)   # one a window
+                    record(ch.last_step, losses[ch.length - 1],
+                           vnorms[ch.length - 1], ch.alpha_last, ch.wire_end)
+                if ch.ckpt_next is not None:
+                    cur_state, cur_key = (carry if device_sampling
+                                          else (carry, None))
+                    save_ckpt(cur_state, cur_key, ch.ckpt_next,
+                              slot_at=ch.slot_end, wire_at=ch.wire_end,
+                              loader_state=ch.loader_state)
         state = carry[0] if device_sampling else carry
         if device_sampling:
             key = carry[1]

@@ -227,9 +227,11 @@ def test_extra_metric_recorders():
                      })
     # wire_bytes is the always-present driver-supplied column (transport
     # backend byte accounting); user recorders ride alongside it, plus the
-    # scalar transfer-ledger entries the resident path is gated on
+    # scalar transfer-ledger entries the resident path is gated on (events
+    # and their bytes)
     assert set(res.extras) == {"max_abs", "nnz", "wire_bytes",
-                               "transfers_h2d", "transfers_d2h"}
+                               "transfers_h2d", "transfers_d2h",
+                               "bytes_h2d", "bytes_d2h"}
     for name in ("max_abs", "nnz", "wire_bytes"):
         assert res.extras[name].shape == res.history.objective.shape
     assert res.extras["max_abs"][-1] > 0.0

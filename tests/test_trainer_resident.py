@@ -67,7 +67,12 @@ def test_resident_transfers_are_o1_per_log_window():
     n_windows = len(res["step"])           # 0, 5, 10, 15, 20
     assert n_windows == 5
     # ONE staging put for all chunks + the shard buffer; ONE pull per window
-    assert res["transfers"] == {"h2d": 1, "d2h": n_windows}
+    ledger = res["transfers"]
+    assert {k: ledger[k] for k in ("h2d", "d2h")} == \
+        {"h2d": 1, "d2h": n_windows}
+    # every step's (loss, v_norm) pair of f32s crosses exactly once
+    assert ledger["bytes_d2h"] == 2 * 4 * tc.num_steps
+    assert ledger["bytes_h2d"] > TOKENS.nbytes // 2
 
 
 def test_resident_dispatch_is_transfer_free_under_xla_guard():
