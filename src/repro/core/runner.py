@@ -24,13 +24,16 @@ Three execution paths:
   host<->device round trip per chunk (ship the stacked minibatch tree in,
   pull metrics out at each record).  The resident path removes that seam:
   the run is PLANNED on host first (chunk schedule, gossip products, step
-  sizes, minibatch indices — all data-independent), staged to the device in
-  ONE ``jax.device_put``, executed chunk-by-chunk with DONATED carries (XLA
-  updates the stacked iterate in place instead of copying the (m, d)
-  buffers), and metrics are recorded by a jitted on-device kernel into
-  preallocated buffers (objective via the vmap'd loss + prox, consensus via
-  ``jnp`` norms) that are pulled to host ONCE at run end — O(1) transfers
-  per run instead of two per chunk.  ``sampling="host"`` (default) draws
+  sizes, minibatch indices — all data-independent) and laid out as ONE host
+  array per input leaf holding every chunk's steps back to back, staged to
+  the device in one ``jax.device_put`` (one buffer per leaf, however many
+  chunks), executed chunk-by-chunk with DONATED carries (XLA updates the
+  stacked iterate in place instead of copying the (m, d) buffers; each
+  chunk reads its rows from an offset carried on the device), and metrics
+  are recorded by a jitted on-device kernel into preallocated buffers
+  (objective via the vmap'd loss + prox, consensus via ``jnp`` norms) that
+  are pulled to host ONCE at run end — O(1) transfers per run instead of
+  two per chunk.  ``sampling="host"`` (default) draws
   minibatch indices from the same ``np.random`` stream as the other paths
   (histories agree to float tolerance); ``sampling="device"`` instead
   threads a ``jax.random`` key through the scan carry and gathers
@@ -38,7 +41,9 @@ Three execution paths:
   sample stream, and nothing per-step ever leaves the device.
   ``RunResult.extras['transfers_h2d'/'transfers_d2h']`` reports the
   driver-initiated transfer events for every path, and
-  ``extras['bytes_h2d'/'bytes_d2h']`` the bytes they moved.  A resident
+  ``extras['bytes_h2d'/'bytes_d2h']`` the bytes they moved;
+  ``extras['staged_buffers']`` counts the arrays a resident job's staging
+  ``device_put`` receives (one per input leaf).  A resident
   job marks its host phases (plan, stage, dispatch, pull) and its compiled
   chunks their device work with the names of :mod:`repro.core.spans`.
 
@@ -110,6 +115,7 @@ import collections
 import contextlib
 import functools
 import inspect
+import math
 import warnings
 import weakref
 from typing import Any, Callable, NamedTuple
@@ -522,6 +528,72 @@ def _chunk_body(data, *, step_fn, meta, device_sampling: bool,
     return body
 
 
+def _stage_rows(xs, grouped):
+    """The run-level host xs in the form they are staged in, and each
+    leaf's per-step shape.  A leaf of ``(rows, *step)`` is staged flat,
+    rows after rows, so a chunk's rows are one contiguous run of the
+    buffer: a leading rows axis lets the TPU lay rows out as the minor
+    axis (whenever ``step``'s own minor axis is not a multiple of 128),
+    which makes a chunk's rows strided.  A component ``grouped`` on its
+    axis 1 (the node or cell axis a sharded run splits) is staged as
+    ``(step[0], rows * rest)`` instead, so it shards on axis 0.
+    ``grouped`` has one bool per xs component."""
+    def form(a, group):
+        if group:
+            return np.ascontiguousarray(np.swapaxes(a, 0, 1)).reshape(
+                a.shape[1], -1)
+        return a.reshape(-1)
+
+    staged = jax.tree.map(
+        lambda g, comp: jax.tree.map(lambda a: form(a, g), comp),
+        grouped, xs)
+    return staged, tuple(np.shape(a)[1:] for a in jax.tree.leaves(xs))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _pad_rows(staged, steps: int):
+    """Pad every leaf staged by :func:`_stage_rows` (and placed on the
+    device) from ``steps`` rows to the next power of two with zero rows,
+    on the device: the executors then compile for O(log) run lengths,
+    while only the real rows cross from the host.  Nothing reads the
+    padding rows."""
+    rows = 1 << max(steps - 1, 0).bit_length()
+    return jax.tree.map(
+        lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1)
+                          + [(0, a.shape[-1] // steps * (rows - steps))]),
+        staged)
+
+
+def _rows(a, offset, length: int, shape: tuple):
+    """Rows ``[offset, offset + length)`` of a leaf staged by
+    :func:`_stage_rows`, as ``(length, *shape)``."""
+    groups = a.shape[0] if a.ndim == 2 else 1
+    size = math.prod(shape) // groups
+    rows = jax.lax.dynamic_slice_in_dim(a, offset * size, length * size,
+                                        axis=a.ndim - 1)
+    return jnp.moveaxis(rows.reshape(groups, length, size), 0, 1).reshape(
+        length, *shape)
+
+
+def _window_scan(scan, carry, xs, length: int, shapes: tuple,
+                 device_sampling: bool):
+    """Run one chunk over its rows of the staged run-level ``xs`` (leaf
+    per-step ``shapes``, both from :func:`_stage_rows`).  ``carry`` is
+    ``(state, offset)``, or ``(state, key, offset)`` under device sampling:
+    the chunk reads rows ``[offset, offset + length)`` of every leaf and
+    hands on the offset advanced by its static bucket ``length``.  The
+    offset lives on the device inside the donated carry, so a dispatch
+    moves nothing from the host.  ``scan(body_carry, window)`` runs the
+    chunk body; both resident executors (single run and batched sweep)
+    read their inputs through here."""
+    *inner, offset = carry
+    leaves, treedef = jax.tree.flatten(xs)
+    window = treedef.unflatten([_rows(a, offset, length, shape)
+                                for a, shape in zip(leaves, shapes)])
+    inner = scan(tuple(inner) if device_sampling else inner[0], window)
+    return (*(inner if device_sampling else (inner,)), offset + length)
+
+
 def _resolve_kernel_step(algo, kernel: str):
     """The chunk body's step for a ``kernel=`` mode: the algorithm's fused
     twin (``AlgoMeta.fused_step``) for "pallas"/"auto" when the method
@@ -541,10 +613,13 @@ def _make_resident_exec(algo, sampling: str, transitions: bool = False,
     chunk); with ``sampling="device"`` the carry additionally threads a
     ``jax.random`` key and minibatches are gathered from the device-resident
     dataset inside the scan body, so the chunk's xs carry no batch tree at
-    all.  With ``transitions=True`` the xs additionally carry per-step
-    outer-transition flags (outer-before, outer-after for coin-flip
-    snapshots, end-of-round + its K) and the body applies the algorithm's
-    TRACED transitions under ``lax.cond`` — no host dispatch per round.
+    all.  The executor takes the staged run-level xs, the chunk's static
+    bucket ``length`` and the leaves' static per-step ``shapes``, and reads
+    its rows at the carried offset (:func:`_window_scan`).  With
+    ``transitions=True`` the xs additionally carry per-step outer-transition
+    flags (outer-before, outer-after for coin-flip snapshots, end-of-round +
+    its K) and the body applies the algorithm's TRACED transitions under
+    ``lax.cond`` — no host dispatch per round.
     ``kernel`` swaps the fused resident-step body in (see
     :func:`_resolve_kernel_step`); the executor-cache key structure is
     unchanged — the fused step rides the step-identity slot."""
@@ -563,14 +638,15 @@ def _make_resident_exec(algo, sampling: str, transitions: bool = False,
                and end_fn is not None and algo.end_outer is not None)
 
     def make():
-        @functools.partial(jax.jit, donate_argnums=0)
-        def exec_chunk(carry, xs, data):
+        @functools.partial(jax.jit, donate_argnums=0, static_argnums=(3, 4))
+        def exec_chunk(carry, xs, data, length, shapes):
             body = _chunk_body(
                 data, step_fn=step_fn, meta=meta,
                 device_sampling=device_sampling, transitions=transitions,
                 outer_fn=outer_fn, end_fn=end_fn, has_opre=has_opre,
                 has_opost=has_opost, has_end=has_end)
-            return jax.lax.scan(body, carry, xs)[0]
+            return _window_scan(lambda c, w: jax.lax.scan(body, c, w)[0],
+                                carry, xs, length, shapes, device_sampling)
 
         return exec_chunk
 
@@ -603,14 +679,13 @@ def _shield_for_donation(tree):
     return jax.tree.map(lambda a: jnp.array(a, copy=True), tree)
 
 
-class _Chunk(NamedTuple):
-    xs: Any                        # host-side stacked xs for one chunk
-
-
 class _Plan(NamedTuple):
     ops: list                      # ("chunk", i) | ("outer",) |
     #                                ("end_outer", K) | ("record",)
-    chunks: list
+    xs: Any                        # host xs of the whole run: every chunk's
+    #                                steps back to back in one array per
+    #                                leaf
+    lengths: list                  # chunk i's (static) bucket length
     cols: dict                     # host-computable history columns
     wire: np.ndarray               # cumulative wire bytes per record
     num_records: int
@@ -635,13 +710,18 @@ def _plan_resident(cells: "list[_PlanCell]", *, m: int, n: int,
     device: chunk boundaries, bucket padding, gossip products, step sizes,
     minibatch indices (``sampling="host"``: same ``np.random`` draw order as
     the host/scan paths — per step, batch indices then the loopless coin
-    flip), and every host-computable history column.  The result is staged
-    in one transfer and executed without further host involvement.
+    flip), and every host-computable history column.  Each chunk is
+    bucket-padded (:func:`_bucket_length`) and laid behind the previous
+    one, so the run's xs are ONE host array per leaf (``plan.xs``) and
+    chunk ``i`` is the next ``plan.lengths[i]`` rows.  The result is staged
+    in one ``device_put`` of one buffer per leaf (padded on the device to
+    a power-of-two row count, :func:`_pad_rows`) and executed without
+    further host involvement.
 
     ``cells`` is one entry per sweep cell (cell metas must agree on loop
     STRUCTURE — the sweep driver validates; numeric values like step sizes,
     rng streams, and snapshot probabilities vary per cell).  With
-    ``batched=True`` the chunk xs grow a cell axis (batches/alphas at axis
+    ``batched=True`` the xs grow a cell axis (batches/alphas at axis
     1, phis only when cells gossip over distinct schedules) and the
     per-cell history columns stack to (records, cells).  With
     ``transitions=True`` the plan contains NO host ``outer``/``end_outer``
@@ -663,7 +743,10 @@ def _plan_resident(cells: "list[_PlanCell]", *, m: int, n: int,
     phi_batched = batched and multi_aux
 
     ops: list = []
-    chunks: list = []
+    lengths: list = []
+    # the run's per-step inputs, every chunk's (bucket-padded) steps in order
+    run = {"idx": [], "phi": [], "alpha": [], "keep": [], "o_pre": [],
+           "o_post": [], "e_post": [], "e_k": []}
     cols = {"epochs": [], "comm_rounds": [], "steps": []}
     wire_col: list = []
 
@@ -710,47 +793,23 @@ def _plan_resident(cells: "list[_PlanCell]", *, m: int, n: int,
         return np.zeros(B, np.bool_) if opost_batched else False
 
     def finish_chunk(idxs, phis, alphas, flags, chunk):
-        """Bucket-pad and stack one chunk's xs on host (batch gather is ONE
-        vectorized take per leaf — same indices as per-step sampling).
+        """Bucket-pad one chunk's per-step inputs with masked-out repeats
+        of its last step and lay them behind the previous chunks'.
         Transition flags pad with False/0 so padded steps never fire an
         outer transition."""
         bucket = _bucket_length(chunk, record_every)
         pad = bucket - chunk
-        if pad:
-            if idxs:
-                idxs.extend(idxs[-1:] * pad)
-            phis.extend(phis[-1:] * pad)
-            alphas.extend(alphas[-1:] * pad)
-        keep = np.array([True] * chunk + [False] * pad, np.bool_)
-        phis_st = jax.tree.map(lambda *l: _stack_wire(l), *phis)
-        alphas_st = np.asarray(alphas, np.float32)   # (T,) or (T, B)
-        if host_sampling:
-            idx = np.stack(idxs)      # (bucket, m, bsz) or (bucket, B, m, bsz)
-            if batched:
-                batch = jax.tree.map(
-                    lambda a: np.take_along_axis(
-                        a[None, None],
-                        idx.reshape(bucket, B, m, bsz,
-                                    *([1] * (a.ndim - 2))),
-                        axis=3), host_data)
-            else:
-                batch = jax.tree.map(
-                    lambda a: np.take_along_axis(
-                        a[None],
-                        idx.reshape(bucket, m, bsz, *([1] * (a.ndim - 2))),
-                        axis=2), host_data)
-            xs = (batch, phis_st, alphas_st, keep)
-        else:
-            xs = (phis_st, alphas_st, keep)
+        run["idx"] += idxs + idxs[-1:] * pad
+        run["phi"] += phis + phis[-1:] * pad
+        run["alpha"] += alphas + alphas[-1:] * pad
+        run["keep"] += [True] * chunk + [False] * pad
         if transitions:
-            fpad = [False] * pad
-            o_post = flags["o_post"] + [_no_flip()] * pad
-            xs = xs + (np.array(flags["o_pre"] + fpad, np.bool_),
-                       np.asarray(o_post, np.bool_),
-                       np.array(flags["e_post"] + fpad, np.bool_),
-                       np.array(flags["e_k"] + [0.0] * pad, np.float32))
-        ops.append(("chunk", len(chunks)))
-        chunks.append(_Chunk(xs))
+            run["o_pre"] += flags["o_pre"] + [False] * pad
+            run["o_post"] += flags["o_post"] + [_no_flip()] * pad
+            run["e_post"] += flags["e_post"] + [False] * pad
+            run["e_k"] += flags["e_k"] + [0.0] * pad
+        ops.append(("chunk", len(lengths)))
+        lengths.append(bucket)
 
     def draw_idx():
         per_cell = [c.rng.integers(0, n, size=(m, bsz)) for c in cells]
@@ -866,6 +925,8 @@ def _plan_resident(cells: "list[_PlanCell]", *, m: int, n: int,
                 plan_record()
 
     num_records = sum(1 for op in ops if op[0] == "record")
+    xs = _run_xs(run, host_data if host_sampling else None,
+                 transitions=transitions, m=m, bsz=bsz)
     if batched:
         cols_np = {
             "epochs": np.array(cols["epochs"], np.float64),
@@ -879,9 +940,37 @@ def _plan_resident(cells: "list[_PlanCell]", *, m: int, n: int,
     else:
         cols_np = {k: np.array(v) for k, v in cols.items()}
         wire_np = np.array(wire_col, dtype=np.int64)
-    return _Plan(ops=ops, chunks=chunks, cols=cols_np, wire=wire_np,
-                 num_records=num_records, phi_batched=phi_batched,
-                 opost_batched=opost_batched)
+    return _Plan(ops=ops, xs=xs, lengths=lengths, cols=cols_np,
+                 wire=wire_np, num_records=num_records,
+                 phi_batched=phi_batched, opost_batched=opost_batched)
+
+
+def _run_xs(run: dict, host_data, *, transitions: bool, m: int, bsz: int):
+    """Stack the run's per-step inputs into one host array per xs leaf:
+    ``(batch, phi, alpha, keep)`` (``batch`` only when ``host_data`` is
+    given, gathered in ONE vectorized take per leaf with the planned
+    indices), then the four transition flags when ``transitions``.  ``()``
+    for a run of no steps."""
+    if not run["keep"]:
+        return ()
+    xs = (jax.tree.map(lambda *l: _stack_wire(l), *run["phi"]),
+          np.asarray(run["alpha"], np.float32),     # (T,) or (T, B)
+          np.array(run["keep"], np.bool_))
+    if host_data is not None:
+        idx = np.stack(run["idx"])      # (T, m, bsz) or (T, B, m, bsz)
+        lead = idx.shape[:-2]
+        batch = jax.tree.map(
+            lambda a: np.take_along_axis(
+                a[(None,) * len(lead)],
+                idx.reshape(*lead, m, bsz, *([1] * (a.ndim - 2))),
+                axis=len(lead) + 1), host_data)
+        xs = (batch,) + xs
+    if transitions:
+        xs += (np.array(run["o_pre"], np.bool_),
+               np.asarray(run["o_post"], np.bool_),
+               np.array(run["e_post"], np.bool_),
+               np.array(run["e_k"], np.float32))
+    return xs
 
 
 def _nbytes(tree) -> int:
@@ -889,10 +978,6 @@ def _nbytes(tree) -> int:
     a copy."""
     return sum(leaf.nbytes if hasattr(leaf, "nbytes")
                else np.asarray(leaf).nbytes for leaf in jax.tree.leaves(tree))
-
-
-def _staged_bytes(chunks) -> int:
-    return _nbytes([c.xs for c in chunks])
 
 
 def _leaves_on(tree, device: bool) -> list:
@@ -980,16 +1065,18 @@ def _run_resident(algo, problem, backend, aux, rng, *, m: int,
             "extra_metrics callables need the host or scan path")
     has_batch = meta.batch_size > 0
     device_sampling = has_batch and sampling == "device"
+    host_sampling = has_batch and sampling == "host"
     transitions = _resolve_transitions(algo, device_transitions)
 
     run_id = spans.next_run()
 
     # one host copy of the dataset for index gathering (the scan path pays
     # the same once-per-run pull); device sampling skips it entirely
-    if has_batch and sampling == "host":
+    if host_sampling:
         pulled = _leaves_on(problem.full_data, True)
         d2h = _moved(transfers, "d2h", pulled) if pulled else 0
-        with spans.span(spans.STAGE, run=run_id, h2d_bytes=0, d2h_bytes=d2h):
+        with spans.span(spans.STAGE, run=run_id, h2d_bytes=0, d2h_bytes=d2h,
+                        h2d_buffers=0):
             host_data = jax.tree.map(np.asarray, problem.full_data)
     else:
         host_data = None
@@ -1003,7 +1090,7 @@ def _run_resident(algo, problem, backend, aux, rng, *, m: int,
             param_count=param_count, record_every=record_every,
             sampling=sampling, host_data=host_data, transitions=transitions)
         plan_span.set_metadata(steps=int(plan.cols["steps"][-1]),
-                               chunks=len(plan.chunks))
+                               chunks=len(plan.lengths))
 
     exec_chunk = _make_resident_exec(algo, sampling, transitions, kernel)
     record_kernel = _make_record_kernel(problem, meta)
@@ -1022,37 +1109,28 @@ def _run_resident(algo, problem, backend, aux, rng, *, m: int,
             return node0 if (getattr(l, "ndim", 0) >= 1
                              and l.shape[0] == m) else rep
 
-        def _xs_shardings(xs):
-            # components follow _plan_resident's xs layout: a host-sampled
-            # batch tree leads with leaves (bucket, m, bsz, ...) — node axis
-            # at 1; phis / alphas / keep / transition flags are tiny and
-            # stay replicated
-            out = []
-            for i, comp in enumerate(xs):
-                if has_batch and sampling == "host" and i == 0:
-                    out.append(jax.tree.map(
-                        lambda l: NS(smesh, P(None, saxis)), comp))
-                else:
-                    out.append(jax.tree.map(lambda l: rep, comp))
-            return tuple(out)
-
+    # the carry: (state, offset), or (state, key, offset) under device
+    # sampling — the offset is the first xs row of the next chunk, advanced
+    # on the device by each chunk (_window_scan); host transitions replace
+    # the state only
     def pack(state):
-        if device_sampling:
-            key = jax.random.PRNGKey(key_seed)
-            if shard == "nodes":
-                key = jax.device_put(key, rep)
-            return (state, key)
-        return state
+        carry = (state, jax.random.PRNGKey(key_seed)) if device_sampling \
+            else (state,)
+        carry += (jnp.zeros((), jnp.int32),)
+        if shard == "nodes":
+            carry = (state,) + jax.device_put(carry[1:], rep)
+        return carry
 
     def unpack(carry):
-        return carry[0] if device_sampling else carry
+        return carry[0]
 
     def repack(carry, state):
-        return (state, carry[1]) if device_sampling else state
+        return (state,) + carry[1:]
 
     # dataset staging only transfers when the problem holds host arrays
-    # (jnp.asarray on a committed device array is a no-op).  ONE staging
-    # transfer ships every chunk's xs (and nothing per-step thereafter); the
+    # (jnp.asarray on a committed device array is a no-op).  One
+    # ``device_put`` stages the run's xs as one buffer per leaf — however
+    # many chunks the run has — and nothing moves per step thereafter; the
     # shielded state copy protects caller-owned buffers (problem.x0) from
     # the donated carries.  NOTE the memory trade: host-sampled batches for
     # the WHOLE run live on device at once — O(num_steps * m * batch *
@@ -1060,19 +1138,28 @@ def _run_resident(algo, problem, backend, aux, rng, *, m: int,
     # batches at all)
     pushed = _leaves_on(problem.full_data, False)
     h2d = _moved(transfers, "h2d", pushed) if pushed else 0
-    _warn_staging(_staged_bytes(plan.chunks))
-    h2d += _moved(transfers, "h2d", [c.xs for c in plan.chunks])
-    with spans.span(spans.STAGE, run=run_id, h2d_bytes=h2d, d2h_bytes=0):
+    _warn_staging(_nbytes(plan.xs))
+    h2d += _moved(transfers, "h2d", plan.xs)
+    staged_buffers = len(jax.tree.leaves(plan.xs))
+    with spans.span(spans.STAGE, run=run_id, h2d_bytes=h2d, d2h_bytes=0,
+                    h2d_buffers=staged_buffers):
+        # under shard="nodes" the host-sampled batch tree (leaves (rows, m,
+        # bsz, ...)) is split on its node axis; phis / alphas / keep /
+        # transition flags are tiny and stay replicated
+        grouped = tuple(shard == "nodes" and host_sampling and i == 0
+                        for i in range(len(plan.xs)))
+        host_xs, shapes = _stage_rows(plan.xs, grouped)
         if shard == "nodes":
             data_dev = jax.device_put(problem.full_data,
                                       jax.tree.map(_node_leaf,
                                                    problem.full_data))
-            staged = jax.device_put(
-                [c.xs for c in plan.chunks],
-                [_xs_shardings(c.xs) for c in plan.chunks])
+            staged = jax.device_put(host_xs, jax.tree.map(
+                lambda g, comp: jax.tree.map(
+                    lambda _: node0 if g else rep, comp), grouped, host_xs))
         else:
             data_dev = jax.tree.map(jnp.asarray, problem.full_data)
-            staged = jax.device_put([c.xs for c in plan.chunks])
+            staged = jax.device_put(host_xs)
+        staged = _pad_rows(staged, sum(plan.lengths))
 
         state = algo.init()
         state = inject_mix_state(algo, backend, aux, state)
@@ -1099,7 +1186,8 @@ def _run_resident(algo, problem, backend, aux, rng, *, m: int,
             kind = op[0]
             if kind == "chunk":
                 with guard():
-                    carry = exec_chunk(carry, staged[op[1]], data_dev)
+                    carry = exec_chunk(carry, staged, data_dev,
+                                       plan.lengths[op[1]], shapes)
             elif kind == "record":
                 with guard():
                     bufs = record_kernel(bufs,
@@ -1124,7 +1212,8 @@ def _run_resident(algo, problem, backend, aux, rng, *, m: int,
         epochs=plan.cols["epochs"],
         comm_rounds=plan.cols["comm_rounds"],
         steps=plan.cols["steps"])
-    extras = {"wire_bytes": plan.wire, **_ledger(transfers)}
+    extras = {"wire_bytes": plan.wire, **_ledger(transfers),
+              "staged_buffers": staged_buffers}
     return RunResult(params=algo.get_params(unpack(carry)), history=history,
                      extras=extras)
 
@@ -1209,7 +1298,8 @@ def run(algo: algorithm_lib.Algorithm,
 
                   * ``scan``: the ``lax.scan`` chunked fast path.
                   * ``resident``: keep the entire run device-resident —
-                    plan on host, stage in one transfer, execute donated
+                    plan on host, stage one buffer per input leaf for
+                    the whole run in one transfer, execute donated
                     compiled chunks, record metrics on device, pull the
                     history once at run end.
                   * ``sampling``: "host" (default) draws minibatch indices

@@ -39,7 +39,8 @@ SCOPES = (SAMPLE, GRAD, SNAPSHOT, UPDATE, MIX, PROX, FUSED_UPDATE, RECORD)
 
 # host spans
 PLAN = "repro.plan"                  # args: steps, chunks
-STAGE = "repro.stage"                # args: h2d_bytes, d2h_bytes
+STAGE = "repro.stage"                # args: h2d_bytes, d2h_bytes,
+#                                      h2d_buffers
 DISPATCH = "repro.dispatch"          # args: dispatches
 PULL = "repro.pull"                  # args: d2h_bytes
 CKPT = "repro.ckpt"                  # args: d2h_bytes

@@ -7,14 +7,16 @@ cell through ``runner.run(resident=True)`` still pays one staging transfer
 and one dispatch loop PER CELL.  :func:`run_sweep` removes that seam: the
 grid expands into a batch axis, the per-cell control flow (identical by
 construction — the driver validates it) is planned ONCE, every cell's
-inputs are staged in a single ``jax.device_put``, the donated chunk
-executors are ``jax.vmap``-ped over the cell axis, outer-round transitions
-run inside the compiled chunks (``lax.cond`` on the precomputed round
-schedule, via the ``Algorithm.outer_traced`` contract — zero per-round host
-dispatches), and ONE stacked history comes back at the end.  An entire fig
-sweep is one device program with O(1) host<->device transfers total — and
-every cell runs under the exact schedule every other cell sees, which is
-what makes GT-SVRG-style cross-method comparisons meaningful.
+inputs are laid out as one array per input leaf for the whole run and
+staged in a single ``jax.device_put`` (one buffer per leaf, however many
+chunks), the donated chunk executors are ``jax.vmap``-ped over the cell
+axis, outer-round transitions run inside the compiled chunks (``lax.cond``
+on the precomputed round schedule, via the ``Algorithm.outer_traced``
+contract — zero per-round host dispatches), and ONE stacked history comes
+back at the end.  An entire fig sweep is one device program with O(1)
+host<->device transfers total — and every cell runs under the exact
+schedule every other cell sees, which is what makes GT-SVRG-style
+cross-method comparisons meaningful.
 
 The contract
 ------------
@@ -110,8 +112,10 @@ class SweepResult(NamedTuple):
     ``grid`` is the expanded cell list (reserved axes included).
     ``extras['wire_bytes']`` is ``(records, cells)``;
     ``extras['transfers_h2d'/'transfers_d2h']`` count driver-initiated
-    transfer events for the WHOLE sweep (O(1) on the batched path), and
-    ``extras['bytes_h2d'/'bytes_d2h']`` the bytes they moved."""
+    transfer events for the WHOLE sweep (O(1) on the batched path),
+    ``extras['bytes_h2d'/'bytes_d2h']`` the bytes they moved, and
+    ``extras['staged_buffers']`` the arrays the resident staging received
+    (one per input leaf on the batched path, whatever the chunk count)."""
 
     grid: list
     params: Any
@@ -273,8 +277,9 @@ def _trace_build(build: Callable, cell: dict):
 # ---------------------------------------------------------------------------
 
 def _xs_axes(meta, sampling: str, plan) -> tuple:
-    """vmap in_axes over one chunk's xs: per-cell leaves carry the cell
-    axis at position 1 (behind scan's time axis), shared leaves are None."""
+    """vmap in_axes over the run-level xs (and so over a chunk's window of
+    them): per-cell leaves carry the cell axis at position 1 (behind the
+    time axis), shared leaves are None."""
     has_batch = meta.batch_size > 0
     host_sampling = has_batch and sampling == "host"
     axes = (1 if plan.phi_batched else None,   # phis
@@ -295,7 +300,10 @@ def _make_sweep_exec(template, build, sampling: str, plan, cache_key,
     cell: ``jax.vmap`` over the cell axis of the donated carry, with the
     algorithm rebuilt per cell inside the trace (cell hyperparameters are
     tracers) and outer transitions applied under ``lax.cond`` from the
-    per-step flags in the xs."""
+    per-step flags in the xs.  Like the single-run executor it takes the
+    staged run-level xs, the chunk's static bucket ``length`` and the
+    leaves' static per-step ``shapes``, and reads its rows at the offset
+    carried on the device (``runner._window_scan``)."""
     from . import runner as runner_lib
 
     meta = template.meta
@@ -309,7 +317,7 @@ def _make_sweep_exec(template, build, sampling: str, plan, cache_key,
     xs_axes = _xs_axes(meta, sampling, plan)
 
     def make():
-        def exec_impl(carry, xs, data, cells):
+        def exec_impl(carry, xs, data, cells, length, shapes):
             def one_cell(carry_c, xs_c, cell):
                 algo_t, _ = _trace_build(build, cell)
                 # the fused resident-step kernel swaps in exactly as on
@@ -330,10 +338,13 @@ def _make_sweep_exec(template, build, sampling: str, plan, cache_key,
                     has_opost=has_opost, has_end=has_end)
                 return jax.lax.scan(body, carry_c, xs_c)[0]
 
-            return jax.vmap(one_cell, in_axes=(0, xs_axes, 0))(
-                carry, xs, cells)
+            return runner_lib._window_scan(
+                lambda c, w: jax.vmap(one_cell, in_axes=(0, xs_axes, 0))(
+                    c, w, cells),
+                carry, xs, length, shapes, device_sampling)
 
-        return functools.partial(jax.jit, donate_argnums=0)(exec_impl)
+        return functools.partial(jax.jit, donate_argnums=0,
+                                 static_argnums=(4, 5))(exec_impl)
 
     return _shared_sweep_exec(cache_key, make)
 
@@ -450,11 +461,12 @@ def run_sweep(build: Callable,
                 the grid for topology sweeps).
     exec:       an :class:`~repro.core.exec_spec.ExecSpec`; ``None``
                 defaults to ``ExecSpec(resident=True)`` — the sweep is ONE
-                batched device-resident program (single staged transfer,
-                vmapped donated chunk executors, in-chunk outer
-                transitions, one stacked history pull — O(1) transfers for
-                the whole sweep).  ``resident=False`` drives the cells
-                sequentially through the host/scan paths.  ``sampling``,
+                batched device-resident program (one staged transfer of
+                one buffer per input leaf, vmapped donated chunk
+                executors, in-chunk outer transitions, one stacked history
+                pull — O(1) transfers for the whole sweep).
+                ``resident=False`` drives the cells sequentially through
+                the host/scan paths.  ``sampling``,
                 ``gossip``, ``mesh``, ``kernel`` behave as on
                 ``runner.run`` (all cells share one transport; with a
                 ``"schedule"`` axis the wire representations must share
@@ -614,40 +626,40 @@ def run_sweep(build: Callable,
         rep = NS(smesh, PS())
         cell0 = NS(smesh, PS(caxis))
         cell1 = NS(smesh, PS(None, caxis))
-        comp_shard = [cell1 if a == 1 else rep
-                      for a in _xs_axes(meta0, sampling, plan)]
-
-        def _xs_shardings(xs):
-            return tuple(jax.tree.map(lambda _, s=s: s, x)
-                         for x, s in zip(xs, comp_shard))
 
         def _put_cells(tree, sharding):
             return jax.device_put(tree,
                                   jax.tree.map(lambda _: sharding, tree))
     else:
-        _xs_shardings = None
         _put_cells = lambda tree, sharding: tree
 
-    # one dataset staging (shared across cells) + ONE staging transfer for
-    # every chunk's xs and the cell-axis hyperparameter arrays
+    # one dataset staging (shared across cells) + ONE staging transfer of
+    # the run's xs (one buffer per leaf, however many chunks) and the
+    # cell-axis hyperparameter arrays
     pushed = runner_lib._leaves_on(template_problem.full_data, False)
     if pushed:
         runner_lib._moved(transfers, "h2d", pushed)
     data_dev = jax.tree.map(jnp.asarray, template_problem.full_data)
     if shard == "cells":
         data_dev = _put_cells(data_dev, rep)
-    runner_lib._warn_staging(runner_lib._staged_bytes(plan.chunks), cells=B)
+    runner_lib._warn_staging(runner_lib._nbytes(plan.xs), cells=B)
     cell_arrays = _cell_arrays(cells, axis_names)
-    runner_lib._moved(transfers, "h2d", ([c.xs for c in plan.chunks],
-                                         cell_arrays))
+    runner_lib._moved(transfers, "h2d", (plan.xs, cell_arrays))
+    staged_buffers = len(jax.tree.leaves((plan.xs, cell_arrays)))
+    # under shard="cells" the per-cell components (cell axis at 1) are
+    # staged grouped by cell and split on that axis; shared ones replicated
+    axes = _xs_axes(meta0, sampling, plan) if plan.xs else ()
+    grouped = tuple(shard == "cells" and a == 1 for a in axes)
+    host_xs, shapes = runner_lib._stage_rows(plan.xs, grouped)
     if shard == "cells":
         staged, cells_dev = jax.device_put(
-            ([c.xs for c in plan.chunks], cell_arrays),
-            ([_xs_shardings(c.xs) for c in plan.chunks],
+            (host_xs, cell_arrays),
+            (jax.tree.map(lambda g, comp: jax.tree.map(
+                lambda _: cell0 if g else rep, comp), grouped, host_xs),
              {name: cell0 for name in axis_names}))
     else:
-        staged, cells_dev = jax.device_put(
-            ([c.xs for c in plan.chunks], cell_arrays))
+        staged, cells_dev = jax.device_put((host_xs, cell_arrays))
+    staged = runner_lib._pad_rows(staged, sum(plan.lengths))
 
     states = []
     for (algo, _), aux in zip(built, auxes):
@@ -660,15 +672,18 @@ def run_sweep(build: Callable,
     if shard == "cells":
         state_b = _put_cells(state_b, cell0)
 
+    # the carry: (states, offset), or (states, keys, offset) under device
+    # sampling — one xs offset for every cell, advanced on the device
+    offset = jnp.zeros((), jnp.int32)
+    if shard == "cells":
+        offset = jax.device_put(offset, rep)
     if device_sampling:
         keys = jnp.stack([jax.random.PRNGKey(s) for s in key_seeds])
         if shard == "cells":
             keys = jax.device_put(keys, cell0)
-        carry = (state_b, keys)
-        unpack = lambda c: c[0]
+        carry = (state_b, keys, offset)
     else:
-        carry = state_b
-        unpack = lambda c: c
+        carry = (state_b, offset)
 
     bufs = (jnp.zeros((plan.num_records, B), jnp.float32),
             jnp.zeros((plan.num_records, B), jnp.float32),
@@ -685,10 +700,11 @@ def run_sweep(build: Callable,
     for op in plan.ops:
         if op[0] == "chunk":
             with guard():
-                carry = exec_chunk(carry, staged[op[1]], data_dev, cells_dev)
+                carry = exec_chunk(carry, staged, data_dev, cells_dev,
+                                   plan.lengths[op[1]], shapes)
         else:  # ("record",)
             with guard():
-                bufs = record_kernel(bufs, get_params(unpack(carry)),
+                bufs = record_kernel(bufs, get_params(carry[0]),
                                      data_dev, cells_dev)
 
     runner_lib._moved(transfers, "d2h", bufs)
@@ -700,8 +716,9 @@ def run_sweep(build: Callable,
         epochs=plan.cols["epochs"],
         comm_rounds=plan.cols["comm_rounds"],
         steps=plan.cols["steps"])
-    extras = {"wire_bytes": plan.wire, **runner_lib._ledger(transfers)}
-    return SweepResult(grid=cells, params=get_params(unpack(carry)),
+    extras = {"wire_bytes": plan.wire, **runner_lib._ledger(transfers),
+              "staged_buffers": staged_buffers}
+    return SweepResult(grid=cells, params=get_params(carry[0]),
                        history=history, extras=extras)
 
 
@@ -727,7 +744,8 @@ def _run_sequential(built, cells, schedules, seeds, *, record_every,
             [np.asarray(r.extras["wire_bytes"]) for r in results], axis=1),
         **{k: sum(int(r.extras[k]) for r in results)
            for k in ("transfers_h2d", "transfers_d2h", "bytes_h2d",
-                     "bytes_d2h")},
+                     "bytes_d2h", "staged_buffers")
+           if k in results[0].extras},
     }
     params = jax.tree.map(lambda *ls: jnp.stack(ls),
                           *[r.params for r in results])

@@ -7,6 +7,7 @@ device sampling (same convergence envelope, different stream), the AlgoMeta
 
 import dataclasses
 import functools
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -161,16 +162,20 @@ def test_resident_exec_donates_state():
 
     L, m, d = 4, 4, 12
     state = jax.tree.map(lambda a: jnp.array(a, copy=True), algo.init())
-    batch = {"features": jnp.zeros((L, m, 1, d)),
-             "labels": jnp.zeros((L, m, 1))}
-    xs = (batch, jnp.stack([jnp.eye(m)] * L), jnp.ones(L, jnp.float32),
-          jnp.ones(L, bool))
-    compiled = exec_chunk.lower(state, xs, data).compile()
+    carry = (state, jnp.zeros((), jnp.int32))
+    # run-level xs of two chunks; this chunk reads the first L rows
+    batch = {"features": np.zeros((2 * L, m, 1, d), np.float32),
+             "labels": np.zeros((2 * L, m, 1), np.float32)}
+    xs, shapes = runner._stage_rows(
+        (batch, np.stack([np.eye(m)] * 2 * L),
+         np.ones(2 * L, np.float32), np.ones(2 * L, bool)), (False,) * 4)
+    compiled = exec_chunk.lower(carry, xs, data, L, shapes).compile()
     assert "input_output_alias" in compiled.as_text()
 
-    out = exec_chunk(state, xs, data)
+    out, offset = exec_chunk(carry, xs, data, L, shapes)
     assert state.params.is_deleted()          # donated, not copied
     assert not out.params.is_deleted()
+    assert int(offset) == L                   # the next chunk's first row
 
 
 def test_resident_run_shields_caller_buffers():
@@ -224,6 +229,80 @@ def test_resident_dispatch_is_transfer_free_under_xla_guard():
     finally:
         runner._RESIDENT_DISPATCH_GUARD = old
     assert res.history.objective[-1] < res.history.objective[0]
+
+
+# ---------------------------------------------------------------------------
+# run-level staging: one buffer per xs leaf, however many chunks
+# ---------------------------------------------------------------------------
+
+def _staging_dpsvrg(problem, num_outer):
+    return algorithm.ALGORITHMS["dpsvrg"](
+        problem, dpsvrg.DPSVRGHyperParams(alpha=0.3, beta=1.2, n0=3,
+                                          num_outer=num_outer))
+
+
+def _digest(res) -> str:
+    """The bytes of a run's objective and consensus history and its final
+    params."""
+    h = hashlib.sha256()
+    for a in (res.history.objective, res.history.consensus,
+              *jax.tree.leaves(res.params)):
+        h.update(np.ascontiguousarray(np.asarray(a)).tobytes())
+    return h.hexdigest()[:16]
+
+
+# the digests the per-chunk staging (one buffer per leaf per chunk) gave
+# for these jobs; staging one buffer per leaf for the whole run must not
+# change a bit of them
+_PER_CHUNK_DIGESTS = {
+    ("host", True): "59fb876a6022823e",
+    ("host", False): "d6354dcad1c96a01",
+    ("device", True): "5f92dd9bc64f02e7",
+    ("device", False): "93deb3ea3619291b",
+}
+
+
+@pytest.mark.parametrize("transitions", [True, False])
+@pytest.mark.parametrize("sampling", ["host", "device"])
+def test_resident_stages_one_buffer_per_leaf(sampling, transitions):
+    """A DPSVRG job whose chunks take three bucket lengths stages its xs as
+    one buffer per leaf: ``staged_buffers`` is the xs leaf count, the same
+    for a job with twice the outer rounds (twice the chunks), and the
+    history and params are bitwise those of per-chunk staging.  Every
+    dispatch runs under the XLA transfer guard: the chunk offset lives on
+    the device."""
+    data, h, x0 = _setup()
+    problem = _problem(data, h, x0)
+    sched = _sched()
+    spec = ExecSpec(resident=True, sampling=sampling,
+                    device_transitions=transitions, gossip="dense")
+    algo = _staging_dpsvrg(problem, 4)
+    backend = runner.transport.GOSSIP_BACKENDS["dense"]
+    plan = runner._plan_resident(
+        [runner._PlanCell(algo.meta, np.random.default_rng(0), backend,
+                          backend.prepare(sched, algo.meta))],
+        m=4, n=jax.tree.leaves(data)[0].shape[1], param_count=12,
+        record_every=3, sampling=sampling,
+        host_data=jax.tree.map(np.asarray, data), transitions=transitions)
+    assert len(set(plan.lengths)) >= 3
+    leaves = len(jax.tree.leaves(plan.xs))
+    # batch features and labels under host sampling, phi, alpha, keep, and
+    # the four transition flags
+    assert leaves == (2 if sampling == "host" else 0) + 3 + \
+        (4 if transitions else 0)
+
+    old = runner._RESIDENT_DISPATCH_GUARD
+    runner._RESIDENT_DISPATCH_GUARD = lambda: jax.transfer_guard("disallow")
+    try:
+        res = runner.run(algo, problem, sched, spec, seed=5, record_every=3)
+        longer = runner.run(_staging_dpsvrg(problem, 8), problem, sched,
+                            spec, seed=5, record_every=3)
+    finally:
+        runner._RESIDENT_DISPATCH_GUARD = old
+    assert res.extras["staged_buffers"] == leaves
+    assert longer.extras["staged_buffers"] == leaves
+    assert res.extras["transfers_h2d"] == 1
+    assert _digest(res) == _PER_CHUNK_DIGESTS[sampling, transitions]
 
 
 # ---------------------------------------------------------------------------
@@ -392,16 +471,20 @@ def test_resident_kernel_exec_donates_state():
 
     L, m, d = 4, 4, 12
     state = jax.tree.map(lambda a: jnp.array(a, copy=True), algo.init())
-    batch = {"features": jnp.zeros((L, m, 1, d)),
-             "labels": jnp.zeros((L, m, 1))}
-    xs = (batch, jnp.stack([jnp.eye(m)] * L), jnp.ones(L, jnp.float32),
-          jnp.ones(L, bool))
-    compiled = exec_chunk.lower(state, xs, data).compile()
+    carry = (state, jnp.zeros((), jnp.int32))
+    # run-level xs of two chunks; this chunk reads the first L rows
+    batch = {"features": np.zeros((2 * L, m, 1, d), np.float32),
+             "labels": np.zeros((2 * L, m, 1), np.float32)}
+    xs, shapes = runner._stage_rows(
+        (batch, np.stack([np.eye(m)] * 2 * L),
+         np.ones(2 * L, np.float32), np.ones(2 * L, bool)), (False,) * 4)
+    compiled = exec_chunk.lower(carry, xs, data, L, shapes).compile()
     assert "input_output_alias" in compiled.as_text()
 
-    out = exec_chunk(state, xs, data)
+    out, offset = exec_chunk(carry, xs, data, L, shapes)
     assert state.params.is_deleted()          # donated, not copied
     assert not out.params.is_deleted()
+    assert int(offset) == L                   # the next chunk's first row
 
 
 def test_resident_kernel_transfer_ledger_is_o1():

@@ -226,6 +226,59 @@ def test_sharded_nodes_matches_unsharded(run_multi_device):
     assert out["link_sums_exact"] == {"4": True, "3": True}, out
 
 
+_NODES_STAGING_SCRIPT = _PRELUDE + textwrap.dedent("""
+    out = {"devices": len(jax.devices()), "cases": {}}
+    m = 4
+    problem = make_problem(m)
+    ring = graphs.b_connected_ring_schedule(m, b=1, seed=0)
+
+    def job(num_outer):
+        return algorithm.dpsvrg_algorithm(problem, dpsvrg.DPSVRGHyperParams(
+            alpha=0.3, beta=1.2, n0=3, num_outer=num_outer, batch_size=2))
+
+    for sampling in ("host", "device"):
+        for transitions in (True, False):
+            kw = dict(resident=True, gossip="dense", sampling=sampling,
+                      device_transitions=transitions)
+            # record_every 3 cuts DPSVRG's rounds into chunks of three
+            # bucket lengths (3, 1, 2)
+            plain = runner.run(job(4), problem, ring, ExecSpec(**kw),
+                               seed=3, record_every=3)
+            sharded = runner.run(job(4), problem, ring,
+                                 ExecSpec(shard="nodes", **kw),
+                                 seed=3, record_every=3)
+            longer = runner.run(job(8), problem, ring,
+                                ExecSpec(shard="nodes", **kw),
+                                seed=3, record_every=3)
+            out["cases"][f"{sampling}-{transitions}"] = {
+                "err": hist_err(plain, sharded),
+                "params_err": float(np.max(np.abs(
+                    np.asarray(plain.params) - np.asarray(sharded.params)))),
+                "buffers": [plain.extras["staged_buffers"],
+                            sharded.extras["staged_buffers"],
+                            longer.extras["staged_buffers"]],
+                "h2d": sharded.extras["transfers_h2d"]}
+    print(json.dumps(out))
+""")
+
+
+def test_sharded_nodes_stages_one_buffer_per_leaf(run_multi_device):
+    """``shard="nodes"`` stages the run-level xs with one sharding per
+    leaf: as many buffers as the unsharded run, not growing with the
+    chunk count, and the same history and params to float tolerance, for
+    both sampling modes with transitions folded in or dispatched."""
+    out = run_multi_device(_NODES_STAGING_SCRIPT, devices=4)
+    assert out["devices"] == 4
+    for name, case in out["cases"].items():
+        assert case["err"] < 1e-5 and case["params_err"] < 1e-5, (name, case)
+        # batch features and labels (host sampling), phi, alpha, keep, and
+        # the four transition flags
+        want = ((2 if name.startswith("host") else 0) + 3
+                + (4 if name.endswith("True") else 0))
+        assert case["buffers"] == [want] * 3, (name, case)
+        assert case["h2d"] == 1, (name, case)
+
+
 # ---------------------------------------------------------------------------
 # host-side validation (fires before any device work)
 # ---------------------------------------------------------------------------

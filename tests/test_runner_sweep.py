@@ -352,9 +352,24 @@ def test_staging_warning_accounts_batch_axis():
             sampling="host", host_data=host_data, transitions=True,
             batched=cells > 1)
 
-    single = runner._staged_bytes(plan_for(1).chunks)
-    batched = runner._staged_bytes(plan_for(4).chunks)
-    assert batched > 3 * single          # total bytes, not per cell
+    single, batched = plan_for(1), plan_for(4)
+    # both stage one run-level array per xs leaf, rows for every chunk
+    assert len(jax.tree.leaves(single.xs)) == \
+        len(jax.tree.leaves(batched.xs))
+    assert jax.tree.leaves(single.xs)[0].shape[0] == sum(single.lengths)
+    assert runner._nbytes(batched.xs) > 3 * runner._nbytes(single.xs)
+    # total bytes, not per cell
+
+    # a batched sweep's staged buffers do not grow with its chunk count
+    grid = {"seed": [0, 1]}
+    few = sweep.run_sweep(build, grid, _sched(),
+                          ExecSpec(resident=True, gossip="dense"),
+                          record_every=10)
+    many = sweep.run_sweep(build, grid, _sched(),
+                           ExecSpec(resident=True, gossip="dense"),
+                           record_every=3)
+    assert many.history.steps.shape[0] > few.history.steps.shape[0]
+    assert many.extras["staged_buffers"] == few.extras["staged_buffers"]
 
 
 def test_reset_executable_caches_clears_sweep_executors():

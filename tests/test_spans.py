@@ -157,6 +157,8 @@ def test_runner_job_spans_once_each_with_one_run_id(tmp_path):
     assert plan["chunks"] > 0
     assert _sums(found, "h2d_bytes") == res.extras["bytes_h2d"] > 0
     assert _sums(found, "d2h_bytes") == res.extras["bytes_d2h"] > 0
+    # the arrays the staging put receives, one per xs leaf
+    assert _sums(found, "h2d_buffers") == res.extras["staged_buffers"] > 0
     # the pull holds the two history buffers and the slot counter
     pull = next(args for n, _, _, args in found if n == spans.PULL)
     assert pull["d2h_bytes"] == 4 * (2 * len(res.history.steps) + 1)
