@@ -128,25 +128,29 @@ class Setup:
         gets it (DPSVRG: the snapshot's full gradient, which is step 0's
         direction; DSPG: (x0 - x1) / alpha) and of the parameters' change
         over the job.  The snapshot holds x0 in both: DPSVRG refreshes it at
-        step 0 only, DSPG never."""
+        step 0 only, DSPG never.
+
+        Every reference to the checked job's state is dropped before DSPG's
+        one-step job starts: its trees and the new job's do not fit one
+        chip together at the configuration's widths."""
         import jax
         hist = self.train(self.check_steps)
-        state = hist["final_state"]
-        change = ref_lm.leaf_norms(jax.tree.map(
+        state = hist.pop("final_state")
+        answers = {"step": list(hist["step"]),
+                   "loss": [float(v) for v in hist["loss"]],
+                   "v_norm": [float(v) for v in hist["v_norm"]]}
+        answers["change"] = ref_lm.leaf_norms(jax.tree.map(
             lambda x, x0: x - x0, state.params, state.snapshot), _NESTING)
         if self.job["algorithm"] == "dpsvrg":
-            grad = ref_lm.leaf_norms(state.full_grad, _NESTING)
-        else:
-            del state
-            one = self.train(1)["final_state"]
-            alpha = self.job["alpha"]
-            grad = ref_lm.leaf_norms(jax.tree.map(
-                lambda x0, x1: (x0 - x1) / alpha, one.snapshot, one.params),
-                _NESTING)
-        return {"step": list(hist["step"]),
-                "loss": [float(v) for v in hist["loss"]],
-                "v_norm": [float(v) for v in hist["v_norm"]],
-                "grad": grad, "change": change}
+            answers["grad"] = ref_lm.leaf_norms(state.full_grad, _NESTING)
+            return answers
+        del hist, state
+        one = self.train(1)["final_state"]
+        alpha = self.job["alpha"]
+        answers["grad"] = ref_lm.leaf_norms(jax.tree.map(
+            lambda x0, x1: (x0 - x1) / alpha, one.snapshot, one.params),
+            _NESTING)
+        return answers
 
     def reference_answers(self, precision="highest", fault=None) -> dict:
         shards = tok.node_shards(self.stream, self.job["nodes"])

@@ -265,11 +265,14 @@ def run_loaded(entry: dict, workload: dict, config: dict, seed: int,
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(devices), "memory_peak_bytes": memory}
     if trace:
+        from bench import scopes
         from bench import trace as trace_lib
         summary = trace_lib.reduce_dir(window.trace_dir, len(devices))
+        named = scopes.reduce(scopes.events(scopes.newest(window.trace_dir)))
         shutil.rmtree(window.trace_dir, ignore_errors=True)
         ctx = {"workload": workload, "steps": outcome.steps,
                "window_s": window.seconds, "trace": summary,
+               "scopes": scopes.per_step(named, outcome.steps),
                "counts": outcome.counts, "chips": len(devices),
                "peaks": load_peaks(kind)}
         metrics = per_layer(entry, ctx)

@@ -1,6 +1,7 @@
 """collective_us_per_step.paper: device time of collective operations
-(the gossip's collective-permutes) per DPSVRG step of the traced window, on
-the first chip.  Moves paper_step_ms."""
+(the gossip's collective-permutes and the all-reduces of the node average)
+per DPSVRG step of the traced window, on the first chip.  Moves
+paper_step_ms."""
 
 
 def read(ctx):

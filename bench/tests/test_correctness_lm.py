@@ -17,9 +17,10 @@ from bench import calibrate
 from bench.tests import tiny
 
 LM = "lm.danube2.dpsvrg"
+DSPG = "lm.danube2.dspg"
 
 
-@pytest.mark.parametrize("name", [LM, "lm.danube2.dspg"])
+@pytest.mark.parametrize("name", [LM, DSPG])
 def test_sound_run_is_correct(fresh, name):
     line = tiny.run(name)
     assert line["correct"], line["compared"]
@@ -27,7 +28,7 @@ def test_sound_run_is_correct(fresh, name):
     assert line["compiles_in_window"] == 0
 
 
-@pytest.mark.parametrize("name", [LM])
+@pytest.mark.parametrize("name", [LM, DSPG])
 def test_control_is_not_correct(name):
     _, workload, config = tiny.cell(name)
     rows = calibrate.readings(name, [11], 1, faults=(), workload=workload,

@@ -71,3 +71,39 @@ def test_load_reads_the_host_spans_of_a_recorded_trace(tmp_path):
     assert len(spans) == 1 and spans[0][1] > spans[0][0]
     with pytest.raises(ValueError, match="no TPU device plane"):
         trace.reduce_dir(tmp_path, chips=1)
+
+
+def test_self_times_take_nested_ops_out_of_their_container():
+    """A ``while`` over two ops, one of them a ``cond`` over a third; an op
+    that only overlaps the loop's end is not nested in it."""
+    ops = [("while.1", 100, 200), ("fusion.2", 110, 130),
+           ("cond.3", 140, 190), ("fusion.4", 150, 170),
+           ("fusion.5", 190, 210), ("fusion.2", 205, 215)]
+    own = trace.self_times(ops, 100, 212)
+    ns = 1e-9
+    assert own["while.1"] == pytest.approx(30 * ns)     # 100 - 20 - 50
+    assert own["fusion.2"] == pytest.approx(27 * ns)    # 20 + 7, clipped
+    assert own["cond.3"] == pytest.approx(30 * ns)
+    assert own["fusion.4"] == pytest.approx(20 * ns)
+    assert own["fusion.5"] == pytest.approx(20 * ns)
+
+
+def test_collective_readers_read_the_collectives_per_step():
+    from bench import harness
+    ctx = {"trace": trace.reduce(_raw(), chips=2), "steps": 5}
+    total = harness.load_reader("collective_us_per_step.paper")(ctx)
+    exposed = harness.load_reader(
+        "collective_exposed_us_per_step.paper")(ctx)
+    assert total == pytest.approx(1e6 * 25e-9 / 5)
+    assert exposed == pytest.approx(1e6 * 20e-9 / 5)
+
+
+def test_collectives_are_found_by_their_hlo_text():
+    """On the chip an op event is named by its instruction's HLO text."""
+    raw = _raw()
+    raw["devices"][0]["XLA Ops"] = [
+        (f"%{n} = f32[784]{{0}} op()", s, e)
+        for n, s, e in raw["devices"][0]["XLA Ops"]]
+    got = trace.reduce(raw, chips=2)
+    assert got["collective_s"] == pytest.approx(25e-9)
+    assert got["collective_exposed_s"] == pytest.approx(20e-9)

@@ -54,10 +54,13 @@ def test_every_metric_has_a_reader_and_moves_an_end_to_end_metric():
 
 def test_reader_finding_nothing_returns_nothing():
     ctx = {"trace": {"window_s": 1.0, "busy_s": 0.5, "modules_s": {},
-                     "collective_s": 0.0},
-           "steps": 10, "window_s": 1.0, "counts": {}, "chips": 1,
-           "peaks": harness.load_peaks("TPU v5 lite")}
+                     "collective_s": 0.0, "collective_exposed_s": 0.0},
+           "scopes": {}, "steps": 10, "window_s": 1.0, "counts": {},
+           "chips": 1, "peaks": harness.load_peaks("TPU v5 lite")}
     for name in ("mfu.train", "chunk_us_per_step.paper",
                  "record_us_per_step.paper",
-                 "collective_us_per_step.paper"):
+                 "collective_us_per_step.paper",
+                 "collective_exposed_us_per_step.paper",
+                 "grad_us_per_step.train", "opt_us_per_step.train",
+                 "plan_us_per_step.paper", "dispatch_us_per_step.paper"):
         assert harness.load_reader(name)(ctx) is None

@@ -13,8 +13,10 @@ where it is missing, the span of the device events.  Per TPU device plane
   (collective-permute, all-reduce, all-gather, reduce-scatter, all-to-all),
   and the part of it during which no other operation ran on that device;
 * ``breakdown``: the ten device operations that took most time on the first
-  device, and its ten longest idle gaps, each named by the innermost host
-  span that covers the gap's middle.
+  device, each by its own time (a ``while`` or ``cond`` contains the
+  operations of its body on the same line; their time is theirs, not the
+  container's), and its ten longest idle gaps, each named by the innermost
+  host span that covers the gap's middle.
 
 Times are seconds.  ``busy_s`` is averaged over the devices used.
 """
@@ -26,7 +28,9 @@ import re
 
 WINDOW_SPAN = "bench.window"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
-COLLECTIVE = re.compile(r"^(collective-permute|all-reduce|all-gather|"
+# a TPU op event is named by its HLO text, ``%collective-permute-start.3 =
+# f32[...] ...``
+COLLECTIVE = re.compile(r"^%?(collective-permute|all-reduce|all-gather|"
                         r"reduce-scatter|all-to-all)")
 MODULE_NAME = re.compile(r"^(?:jit_)?(.*?)(?:\(\d+\))?$")
 
@@ -107,13 +111,11 @@ def reduce(raw: dict, chips: int) -> dict:
             if part:
                 key = module_name(n)
                 modules[key] = modules.get(key, 0.0) + part * ns
-        by_op: dict = {}
-        for n, s, e in ops:
-            by_op[n] = by_op.get(n, 0.0) + total(clip([(s, e)], lo, hi)) * ns
         per_device.append({"busy": busy, "busy_s": total(busy) * ns,
                            "collective_s": total(clip(coll, lo, hi)) * ns,
                            "collective_exposed_s": exposed * ns,
-                           "modules_s": modules, "ops_s": by_op})
+                           "modules_s": modules,
+                           "ops_s": self_times(ops, lo, hi)})
     first = per_device[0]
     edges = [lo] + [x for iv in first["busy"] for x in iv] + [hi]
     gaps = sorted(((s, e) for s, e in zip(edges[::2], edges[1::2]) if e > s),
@@ -130,6 +132,32 @@ def reduce(raw: dict, chips: int) -> dict:
                       "idle_gaps": [[_host_at(raw["host"], (s + e) / 2),
                                      (e - s) * ns] for s, e in gaps[:10]]},
     }
+
+
+def self_times(ops, lo, hi) -> dict:
+    """Seconds of each op name inside [lo, hi], less the time of the ops
+    nested in it: an op's parent is the innermost earlier op that contains
+    it (ops that only overlap are not nested)."""
+    own: dict = {}
+    stack: list = []            # [name, start, end, children's intervals]
+
+    def finish(entry):
+        n, s, e, children = entry
+        part = total(clip([(s, e)], lo, hi)) - total(union(clip(children,
+                                                                lo, hi)))
+        own[n] = own.get(n, 0.0) + part * 1e-9
+
+    for n, s, e in sorted(ops, key=lambda op: (op[1], -op[2])):
+        while stack and stack[-1][2] <= s:
+            finish(stack.pop())
+        parent = next((entry for entry in reversed(stack) if e <= entry[2]),
+                      None)
+        if parent is not None:
+            parent[3].append((s, e))
+        stack.append([n, s, e, []])
+    for entry in reversed(stack):
+        finish(entry)
+    return own
 
 
 def _intersect(a, b) -> list:
