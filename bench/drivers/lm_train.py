@@ -20,6 +20,12 @@ device; the tracker reads the clock there.  The window opens at the first
 boundary (step 0 done) and closes at the first snapshot-period boundary
 after ``--seconds``, so it holds whole periods; the tracker then stops the
 job.
+
+The model is the architecture module's that the configuration names
+(``"arch"``, ``bench/archs/<arch>.py``): the program's ``ModelConfig``, the
+operation count, the leaf names and the plain model reference all come from
+it; ``bench/reference/lm.py`` holds the training rule every architecture
+shares.
 """
 
 from __future__ import annotations
@@ -31,7 +37,7 @@ import time
 
 import numpy as np
 
-from bench import flops
+from bench import harness
 from bench.harness import Check, Outcome, log
 from bench.reference import lm as ref_lm
 from bench.seeds import derive
@@ -39,9 +45,6 @@ from bench.traffic import tokens as tok
 
 STREAM_TOKENS = 500_000         # the token stream, split over the nodes
 MAX_PERIODS = 40                # snapshot periods planned for the window
-# the program's parameter tree nests a layer's matmuls under "attn" and
-# "ffn" and a norm's gain under "w"; the reference's does not
-_NESTING = ("attn", "ffn", "w")
 
 
 @functools.lru_cache(maxsize=None)
@@ -56,22 +59,10 @@ class _WindowClosed(Exception):
     """Raised by the tracker to stop the measured job at the window's end."""
 
 
-def model_config(config: dict):
-    from repro.models.api import ModelConfig
-    m = config["model"]
-    return ModelConfig(
-        name=config["name"], arch_type="dense",
-        num_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab_size=m["vocab_size"], sliding_window=m["sliding_window"],
-        rope_theta=m["rope_theta"], tie_embeddings=m["tie_word_embeddings"])
-
-
 def flops_per_step(config: dict, job: dict) -> float:
     """Operations the algorithm as written requires per step."""
-    per_token = flops.decoder_train_flops_per_token(config["model"],
-                                                    job["seq_len"])
+    per_token = harness.load_arch(config).train_flops_per_token(
+        config["model"], job["seq_len"])
     tokens = job["nodes"] * job["per_node_batch"] * job["seq_len"]
     if job["algorithm"] == "dpsvrg":
         snap = tokens * job["snapshot_batch_mult"] / job["snapshot_every"]
@@ -81,8 +72,10 @@ def flops_per_step(config: dict, job: dict) -> float:
 
 @dataclasses.dataclass
 class Setup:
-    """What one ``--seed`` makes: the stream, the seeds, the job's pieces."""
+    """What one ``--seed`` makes: the stream, the seeds, the job's pieces,
+    and the architecture module the configuration names."""
     config: dict
+    arch: object
     job: dict
     seeds: dict
     stream: object
@@ -90,11 +83,12 @@ class Setup:
 
     @classmethod
     def build(cls, config: dict, job: dict, seed: int) -> "Setup":
+        arch = harness.load_arch(config)
         seeds = derive(seed, ("init", "loader", "tokens"))
         stream = tok.make_token_stream(STREAM_TOKENS,
                                        config["model"]["vocab_size"],
                                        seeds["tokens"])
-        return cls(config, job, seeds, stream, _l1(job["l1"]))
+        return cls(config, arch, job, seeds, stream, _l1(job["l1"]))
 
     @property
     def check_steps(self) -> int:
@@ -118,7 +112,7 @@ class Setup:
                         seq_len=job["seq_len"], seed=self.seeds["loader"])
         sched = graphs.b_connected_ring_schedule(m, b=job["schedule_b"],
                                                  seed=0)
-        return trainer.train_loop(model_config(self.config),
+        return trainer.train_loop(self.arch.program_config(self.config),
                                   self.prox, sched, data, tc,
                                   tracker=tracker)
 
@@ -134,28 +128,29 @@ class Setup:
         one-step job starts: its trees and the new job's do not fit one
         chip together at the configuration's widths."""
         import jax
+        nesting = self.arch.NESTING
         hist = self.train(self.check_steps)
         state = hist.pop("final_state")
         answers = {"step": list(hist["step"]),
                    "loss": [float(v) for v in hist["loss"]],
                    "v_norm": [float(v) for v in hist["v_norm"]]}
         answers["change"] = ref_lm.leaf_norms(jax.tree.map(
-            lambda x, x0: x - x0, state.params, state.snapshot), _NESTING)
+            lambda x, x0: x - x0, state.params, state.snapshot), nesting)
         if self.job["algorithm"] == "dpsvrg":
-            answers["grad"] = ref_lm.leaf_norms(state.full_grad, _NESTING)
+            answers["grad"] = ref_lm.leaf_norms(state.full_grad, nesting)
             return answers
         del hist, state
         one = self.train(1)["final_state"]
         alpha = self.job["alpha"]
         answers["grad"] = ref_lm.leaf_norms(jax.tree.map(
             lambda x0, x1: (x0 - x1) / alpha, one.snapshot, one.params),
-            _NESTING)
+            nesting)
         return answers
 
     def reference_answers(self, precision="highest", fault=None) -> dict:
         shards = tok.node_shards(self.stream, self.job["nodes"])
-        run = ref_lm.Run(self.config["model"], self.job, self.seeds, shards,
-                         precision=precision, fault=fault)
+        run = ref_lm.Run(self.arch.reference, self.config["model"], self.job,
+                         self.seeds, shards, precision=precision, fault=fault)
         return run.answers(self.check_steps)
 
 

@@ -7,6 +7,15 @@ per-layer metric is a file of its own, found by name:
   job parameters and the limits of its correctness check;
 * ``bench/configs/<config>.json`` holds the configuration as it is run;
 * ``bench/drivers/<driver>.py`` drives one entry point of the program;
+* ``bench/archs/<arch>.py`` is one LM architecture, named by the key
+  ``"arch"`` of an ``lm_train`` configuration.  It exports
+  ``program_config(config)``, the program's ``repro.models.api.ModelConfig``;
+  ``train_flops_per_token(model, seq)``, the operations of one forward and
+  backward pass per token; ``NESTING``, the keys of the program's parameter
+  tree that the reference's does not have; ``TINY``, the model sizes of a
+  run on the CPU; and ``reference``, the plain model module that
+  ``bench/reference/lm.py``'s training rule trains (its docstring says what
+  that module exports);
 * ``bench/metrics/<metric>.py`` reads one per-layer metric from the
   reduced trace and the run's counts.
 
@@ -68,6 +77,20 @@ def load_config(name: str) -> dict:
 
 def load_driver(name: str):
     return importlib.import_module(f"bench.drivers.{name}")
+
+
+def load_arch(config: dict):
+    """The architecture module that an LM configuration names under its key
+    ``"arch"``."""
+    name = config.get("arch")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise ValueError(f"configuration {config.get('name')!r} names no "
+                         f"architecture: its key \"arch\" holds {name!r}")
+    if importlib.util.find_spec(f"bench.archs.{name}") is None:
+        raise ValueError(f"configuration {config.get('name')!r}: its key "
+                         f"\"arch\" names {name!r}, and there is no "
+                         f"bench/archs/{name}.py")
+    return importlib.import_module(f"bench.archs.{name}")
 
 
 def load_reader(metric: str) -> Callable[[dict], float | None]:

@@ -1,11 +1,21 @@
-"""Plain reference of decentralized LM training on a dense decoder.
+"""Plain reference of decentralized LM training: the training rule, which
+every architecture shares.
 
-Written from the architecture's description, in straightforward
-``jax.numpy``: token embedding, per layer a pre-norm block of grouped-query
-attention with rotary positions (rotate-half, sliding-window causal mask)
-and a SwiGLU MLP, a final RMSNorm, and the head (its own matrix, or the
-embedding where the configuration ties them).  The loss is next-token
-cross-entropy.  No kernel, cache, scan or remat.
+The model is a module of its own, ``bench/reference/<module>.py``, named by
+the architecture module's ``reference`` (``bench/archs/<arch>.py``) and
+handed to ``Run``.  It exports
+
+* ``init_params(model, seed, precision)``: one node's parameter tree from
+  ``seed``, in one jitted call, held in ``dtype(precision)``.  It draws its
+  keys in the order the program's ``init_params`` draws them, so that with
+  the program's initialisation rule both start from the same weights;
+* ``loss_fn(params, tokens, labels, model)``: the mean next-token
+  cross-entropy of one node's (B, L) rows, with the matmuls at whatever
+  precision the caller sets.
+
+``model`` is the configuration's ``"model"`` group.  The tree's leaf names
+(``leaf_norms``) are the program's without the keys of the architecture's
+``NESTING``.
 
 The training rule is Algorithm 1 applied to the node-stacked parameters:
 
@@ -14,12 +24,6 @@ The training rule is Algorithm 1 applied to the node-stacked parameters:
     v_i   = grad f_i(x_i; b) - grad f_i(snap_i; b) + mu_i      (DPSVRG)
     v_i   = grad f_i(x_i; b)                                   (DSPG)
     q_i   = x_i - alpha v_i,   q_hat = Phi q,   x' = prox_{alpha h}(q_hat)
-
-Weights follow the published initialisation rule the configuration names
-(``init``): truncated normals at 1/sqrt(fan_in), the embedding at
-1/sqrt(d), norm gains at zero around a unit scale, drawn key by key from one
-seed in the order embedding, then per layer q, k, v, o, gate, up, down,
-then the untied head.
 
 ``precision`` is ``"highest"`` (float32 everywhere, every matmul at
 ``Precision.HIGHEST``) or ``"bfloat16"`` (the control: parameters, state and
@@ -46,108 +50,9 @@ PRECISIONS = ("highest", "bfloat16")
 FAULTS = (None, "unchanged", "half_batch", "no_mix", "answer")
 
 
-def _dtype(precision: str):
+def dtype(precision: str):
+    """The type a model holds its parameters and activations in."""
     return jnp.bfloat16 if precision == "bfloat16" else jnp.float32
-
-
-# ---------------------------------------------------------------------------
-# the model
-# ---------------------------------------------------------------------------
-
-def init_params(model: dict, seed: int, precision: str):
-    """One node's parameters from ``seed`` (one jitted call)."""
-    d, h, kv = model["hidden_size"], model["num_attention_heads"], \
-        model["num_key_value_heads"]
-    hd, ff, vocab = d // h, model["intermediate_size"], model["vocab_size"]
-    layers = model["num_hidden_layers"]
-    untied = not model["tie_word_embeddings"]
-
-    def dense(key, shape):
-        return jax.random.truncated_normal(key, -2.0, 2.0, shape,
-                                           jnp.float32) / math.sqrt(shape[0])
-
-    def make(key):
-        keys = []
-        for _ in range(1 + 7 * layers + untied):
-            key, sub = jax.random.split(key)
-            keys.append(sub)
-        p = {"embed": jax.random.normal(keys[0], (vocab, d), jnp.float32)
-             / math.sqrt(d),
-             "final_norm": jnp.zeros((d,), jnp.float32), "layers": []}
-        for i in range(layers):
-            k = keys[1 + 7 * i:8 + 7 * i]
-            p["layers"].append({
-                "norm1": jnp.zeros((d,), jnp.float32),
-                "wq": dense(k[0], (d, h * hd)),
-                "wk": dense(k[1], (d, kv * hd)),
-                "wv": dense(k[2], (d, kv * hd)),
-                "wo": dense(k[3], (h * hd, d)),
-                "norm2": jnp.zeros((d,), jnp.float32),
-                "w_gate": dense(k[4], (d, ff)),
-                "w_up": dense(k[5], (d, ff)),
-                "w_down": dense(k[6], (ff, d)),
-            })
-        if untied:
-            p["lm_head"] = dense(keys[-1], (d, vocab))
-        return jax.tree.map(lambda a: a.astype(_dtype(precision)), p)
-
-    return jax.jit(make)(jax.random.PRNGKey(seed))
-
-
-def _rms_norm(x, w, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
-                            + eps) * (1.0 + w.astype(jnp.float32))
-    return y.astype(x.dtype)
-
-
-def _rope(x, theta):
-    """x (B, L, H, hd): rotate the two halves of each head by position."""
-    seq, hd = x.shape[1], x.shape[-1]
-    half = hd // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(seq, dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-    return out.astype(x.dtype)
-
-
-def _attention(p, model, x):
-    b, seq, d = x.shape
-    h, kv = model["num_attention_heads"], model["num_key_value_heads"]
-    hd = d // h
-    q = _rope((x @ p["wq"]).reshape(b, seq, h, hd), model["rope_theta"])
-    k = _rope((x @ p["wk"]).reshape(b, seq, kv, hd), model["rope_theta"])
-    v = (x @ p["wv"]).reshape(b, seq, kv, hd)
-    group = jnp.arange(h) // (h // kv)          # query head -> its kv head
-    k, v = k[:, :, group], v[:, :, group]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    scores = scores / math.sqrt(hd)
-    qpos, kpos = jnp.arange(seq)[:, None], jnp.arange(seq)[None, :]
-    allowed = (kpos <= qpos) & (kpos > qpos - model["sliding_window"])
-    scores = jnp.where(allowed, scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, seq, h * hd)
-    return out @ p["wo"]
-
-
-def loss_fn(params, tokens, labels, model):
-    """Mean next-token cross-entropy of one node's batch."""
-    eps = model["rms_norm_eps"]
-    x = params["embed"][tokens]
-    for p in params["layers"]:
-        x = x + _attention(p, model, _rms_norm(x, p["norm1"], eps))
-        hmid = _rms_norm(x, p["norm2"], eps)
-        x = x + (jax.nn.silu(hmid @ p["w_gate"]) * (hmid @ p["w_up"])) \
-            @ p["w_down"]
-    x = _rms_norm(x, params["final_norm"], eps)
-    head = params["lm_head"] if "lm_head" in params else params["embed"].T
-    logits = (x @ head).astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - gold)
 
 
 # ---------------------------------------------------------------------------
@@ -199,21 +104,23 @@ def _minus(a, b, scale=1.0):
 class Run:
     """The first steps of one training job, as the reference sees them."""
 
-    def __init__(self, model: dict, job: dict, seeds: dict,
+    def __init__(self, net, model: dict, job: dict, seeds: dict,
                  shards: np.ndarray, *, precision: str = "highest",
                  fault: str | None = None):
+        """``net`` is the plain model module, ``model`` the sizes it is
+        built at."""
         if precision not in PRECISIONS or fault not in FAULTS:
             raise ValueError(f"unknown precision {precision!r} or fault "
                              f"{fault!r}")
-        self.model, self.job, self.seeds = model, job, seeds
+        self.net, self.model, self.job, self.seeds = net, model, job, seeds
         self.shards = shards
         self.precision, self.fault = precision, fault
         mp = "highest" if precision == "highest" else "default"
 
         def grad_fn(params, tokens, labels):
             with jax.default_matmul_precision(mp):
-                return jax.value_and_grad(loss_fn)(params, tokens, labels,
-                                                   model)
+                return jax.value_and_grad(net.loss_fn)(params, tokens,
+                                                       labels, model)
 
         self._vg = jax.jit(grad_fn)
 
@@ -246,7 +153,8 @@ class Run:
         snap_batch = batch * job["snapshot_batch_mult"]
         starts = tok.StartReplay(self.seeds["loader"], m,
                                  self.shards.shape[1], seq)
-        x0 = init_params(self.model, self.seeds["init"], self.precision)
+        x0 = self.net.init_params(self.model, self.seeds["init"],
+                                  self.precision)
         x = [x0] * m
         snap, mu = list(x), [None] * m
         out = {"loss": []}

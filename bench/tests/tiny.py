@@ -9,11 +9,6 @@ import time
 
 from bench import harness
 
-LM_MODEL = {"hidden_size": 64, "intermediate_size": 128,
-            "num_attention_heads": 4, "num_key_value_heads": 2,
-            "num_hidden_layers": 2, "vocab_size": 256, "sliding_window": 24,
-            "rope_theta": 10000.0, "rms_norm_eps": 1e-06,
-            "tie_word_embeddings": False}
 LM_JOB = {"nodes": 2, "seq_len": 32, "per_node_batch": 2,
           "snapshot_every": 8, "snapshot_batch_mult": 2, "log_every": 4,
           "alpha": 0.05, "trace_seconds": 0.5}
@@ -41,7 +36,7 @@ def cell(name: str, nodes: int | None = None):
     entry = _entry(name, workload["driver"])
     config = copy.deepcopy(harness.load_config(workload["config"]))
     if workload["driver"] == "lm_train":
-        config["model"].update(LM_MODEL)
+        config["model"].update(harness.load_arch(config).TINY)
         workload["job"].update(LM_JOB)
     else:
         config["problem"].update(PAPER_PROBLEM)
